@@ -23,20 +23,6 @@ COLLECTIVES = ("all-gather", "all-reduce", "reduce-scatter", "all-to-all",
                "collective-permute")
 
 
-def normalize_cost_analysis(cost) -> dict:
-    """Normalize ``Compiled.cost_analysis()`` across jaxlib versions.
-
-    Older jaxlibs return a single properties dict; newer ones return a
-    one-element list of dicts (one per partition).  Always returns a plain
-    ``dict`` (empty when the analysis is unavailable), so callers can index
-    ``["flops"]`` etc. without version guards.
-    """
-    if cost is None:
-        return {}
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return dict(cost)
-
 _SHAPE_RE = re.compile(r"([a-z0-9]+)\[([0-9,]*)\]")
 _DTYPE_BYTES = {
     "f64": 8, "f32": 4, "f16": 2, "bf16": 2, "f8e4m3fn": 1, "f8e5m2": 1,

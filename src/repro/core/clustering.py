@@ -27,12 +27,17 @@ __all__ = [
 ]
 
 
-def pairwise_distances(points: np.ndarray, *, backend: str = "jnp") -> np.ndarray:
-    """(N, N) Euclidean distance matrix between task embeddings."""
+def pairwise_distances(points: np.ndarray, *, backend: str = "jnp",
+                       interpret: bool = False) -> np.ndarray:
+    """(N, N) Euclidean distance matrix between task embeddings.
+
+    ``backend="pallas"`` compiles the TPU kernel; ``interpret=True`` runs
+    its body in the Pallas interpreter instead (CPU tests)."""
     if backend == "pallas":
         from repro.kernels.pairwise_affinity import ops as pa_ops
 
-        return np.asarray(pa_ops.pairwise_distance(points, interpret=True))
+        return np.asarray(pa_ops.pairwise_distance(points,
+                                                   interpret=interpret))
     from repro.kernels.pairwise_affinity import ref as pa_ref
 
     return np.asarray(pa_ref.pairwise_distance(points))
@@ -71,12 +76,13 @@ def _cluster_loss_matrix(D: np.ndarray, R: int, lam: float) -> np.ndarray:
 def triplet_agglomerate(points: np.ndarray, *, n_clusters: int = 4,
                         R: int = 3, lam: float = 0.5,
                         dendro_threshold: float | None = None,
-                        backend: str = "jnp") -> ClusteringResult:
+                        backend: str = "jnp",
+                        interpret: bool = False) -> ClusteringResult:
     """Agglomerate N points down to ``n_clusters`` superclusters."""
     points = np.asarray(points, dtype=np.float64)
     N = points.shape[0]
     n_clusters = max(1, min(n_clusters, N))
-    P = pairwise_distances(points, backend=backend)
+    P = pairwise_distances(points, backend=backend, interpret=interpret)
 
     members: list[list[int]] = [[i] for i in range(N)]
     # pair-sum matrix S[a, b] = sum of point distances between clusters a, b

@@ -32,6 +32,7 @@ class CRCHConfig:
     ckpt_lambda: float | None = None  # None -> dynamic lambda* (Lemma 3.1)
     ckpt_gamma: float = 2.0          # per-checkpoint overhead (seconds)
     backend: str = "jnp"             # "jnp" | "pallas" distance matrix
+    interpret: bool = False          # run the Pallas kernel interpreted (CPU)
     busy_terminate: bool = True
     backlog_tol: float = 120.0
 
@@ -52,7 +53,8 @@ def plan(wf: Workflow, env: CloudEnvironment, cfg: CRCHConfig | None = None,
     pca = fit_pca(feats, cfg.cov_threshold)
     clustering = triplet_agglomerate(
         pca.projected, n_clusters=cfg.max_rep_count,
-        R=cfg.triplet_R, lam=cfg.triplet_lambda, backend=cfg.backend)
+        R=cfg.triplet_R, lam=cfg.triplet_lambda, backend=cfg.backend,
+        interpret=cfg.interpret)
     counts = replication_counts(
         clustering, rule_guard=cfg.rule_guard,
         priorities=feats[:, 2], exec_times=feats[:, 0])

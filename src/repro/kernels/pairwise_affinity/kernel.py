@@ -26,6 +26,7 @@ def pairwise_distance_kernel(x_ref, y_ref, out_ref):
     gram = jax.lax.dot_general(
         x, y,
         dimension_numbers=(((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,     # full f32, not one bf16 pass
         preferred_element_type=jnp.float32,
     )                                            # (Bm, Bn) on the MXU
     xsq = jnp.sum(x * x, axis=1, keepdims=True)  # (Bm, 1)

@@ -15,7 +15,6 @@ import jax           # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
 
-from repro.analysis.hlo import normalize_cost_analysis   # noqa: E402
 from repro.configs import ARCHS, get_config              # noqa: E402
 from repro.distributed import params as pshard           # noqa: E402
 from repro.distributed.sharding import use_rules         # noqa: E402
@@ -115,7 +114,7 @@ def collective_bytes(hlo_text: str) -> dict:
 
 def _flatten_cost(cost) -> dict:
     return {k: float(v)
-            for k, v in normalize_cost_analysis(cost).items()
+            for k, v in cost.items()
             if isinstance(v, (int, float))}
 
 

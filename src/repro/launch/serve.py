@@ -9,9 +9,10 @@ continuous engine; ``--static`` explicitly selects the legacy one-shot
 static batch (a baseline, not a fallback), and ``--verify-static`` checks
 the engine's tokens token-for-token against the batch=1 static reference.
 
-On TPU this runs under the production mesh with the ZeRO-1/TP weight layout
-and the sequence-sharded KV cache; on CPU, ``--tiny`` validates the same
-code end-to-end.
+``--chips N`` runs on a ``(1, N)`` data x model mesh over the host's first
+N devices: weights take the TP layout of ``repro.distributed.params`` and
+the KV cache is sharded along ``kv_seq``.  On CPU, ``--tiny`` validates the
+same code end-to-end.
 
     PYTHONPATH=src python -m repro.launch.serve --arch rwkv6-3b --tiny \
         --requests 8 --prompt-len 32 --new-tokens 16 --policy crch \
@@ -20,6 +21,7 @@ code end-to-end.
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import time
 
 import jax
@@ -32,7 +34,7 @@ from repro.configs import get_config
 from repro.distributed import params as pshard
 from repro.distributed.sharding import use_rules
 from repro.distributed.steps import make_prefill_step, make_serve_step
-from repro.launch.mesh import make_debug_mesh, make_production_mesh
+from repro.launch.mesh import enable_compile_cache, make_mesh
 from repro.launch.shapes import make_batch
 from repro.models import lm
 from repro.serve import (EngineConfig, Request, ServeEngine, ServeMetrics,
@@ -105,18 +107,22 @@ def add_chaos_args(ap: argparse.ArgumentParser) -> None:
 
 
 def _sharded_params(cfg, mesh, seed: int):
-    params = lm.init_params(jax.random.key(seed), cfg)
-    abstract = jax.eval_shape(lambda: params)
-    psh = pshard.param_shardings(abstract, mesh, zero1=True)
-    return jax.device_put(params, psh)
+    """Initialise the weights directly into their mesh layout (no full
+    unsharded copy on the first device)."""
+    def init():
+        return lm.init_params(jax.random.key(seed), cfg)
+
+    psh = pshard.param_shardings(jax.eval_shape(init), mesh, zero1=True)
+    return jax.jit(init, out_shardings=psh)()
 
 
 def _make_requests(cfg, n: int, prompt_len: int, new_tokens: int,
-                   seed: int) -> list[Request]:
+                   seed: int, min_prompt_len: int = 0) -> list[Request]:
     rng = np.random.default_rng(seed)
+    lo = max(min_prompt_len or prompt_len // 2, 4)
     reqs = []
     for i in range(n):
-        plen = int(rng.integers(max(prompt_len // 2, 4), prompt_len + 1))
+        plen = int(rng.integers(lo, prompt_len + 1))
         newt = new_tokens if i % 3 else new_tokens * 2
         frames = (rng.normal(size=(cfg.n_frames, cfg.d_model))
                   .astype(np.float32) if cfg.is_encdec else None)
@@ -131,9 +137,26 @@ def _make_requests(cfg, n: int, prompt_len: int, new_tokens: int,
     return reqs
 
 
-def continuous_main(cfg, mesh, args) -> None:
+@dataclasses.dataclass
+class ServeRun:
+    """What one continuous-engine run leaves behind for its caller."""
+    engine: ServeEngine
+    requests: list[Request]
+    params: dict
+    cache_len: int
+    wall_s: float
+    summary: dict
+    reference: dict | None    # rid -> greedy_reference tokens (--verify-static)
+
+
+def continuous_main(cfg, mesh, args, *, record_logits: bool = False
+                    ) -> ServeRun:
+    """Serve ``args.requests`` seeded requests through :class:`ServeEngine`
+    on ``mesh``, print the run's numbers and check what the flags ask for
+    (``--chaos-assert``, ``--verify-static``).  ``record_logits`` keeps the
+    engine's logits (``EngineConfig.record_logits``)."""
     reqs = _make_requests(cfg, args.requests, args.prompt_len,
-                          args.new_tokens, args.seed)
+                          args.new_tokens, args.seed, args.min_prompt_len)
     offset = cfg.n_image_tokens or 0
     cache_len = max(offset + prompt_bucket(r.prompt_len) + r.max_new_tokens
                     for r in reqs)
@@ -157,7 +180,8 @@ def continuous_main(cfg, mesh, args) -> None:
         params = _sharded_params(cfg, mesh, args.seed)
         engine = ServeEngine(
             cfg, EngineConfig(cache_len=cache_len, q_chunk=64,
-                              max_queue_depth=args.max_queue_depth or None),
+                              max_queue_depth=args.max_queue_depth or None,
+                              record_logits=record_logits),
             pool=pool, policy=policy, params=params,
             metrics=ServeMetrics(registry=ctx.registry), chaos=chaos,
             tracer=ctx.tracer)
@@ -170,7 +194,7 @@ def continuous_main(cfg, mesh, args) -> None:
     tok_s = metrics.decode_tokens / max(wall, 1e-9)
     print(f"arch={cfg.name} ({cfg.param_count() / 1e6:.0f}M params) "
           f"requests={args.requests} slots={pool.n_slots} "
-          f"policy={policy.name} env={args.env} mesh={args.mesh}")
+          f"policy={policy.name} env={args.env} chips={args.chips}")
     print(f"{engine.step_no} engine steps in {wall:.2f}s "
           f"({tok_s:.1f} tok/s aggregate) | completed "
           f"{int(s['completed'])}/{args.requests} "
@@ -211,6 +235,7 @@ def continuous_main(cfg, mesh, args) -> None:
             f"first token — degraded mode must never shed live work")
         print(f"chaos-assert OK: {int(s['completed'])} completed, "
               f"{recoveries} recoveries, 0 past-first-token drops")
+    ref = None
     if args.verify_static:
         with use_rules(mesh):
             ref = greedy_reference(params, cfg, reqs, cache_len, q_chunk=64)
@@ -220,6 +245,8 @@ def continuous_main(cfg, mesh, args) -> None:
               f"{len(reqs) - len(mismatched)}/{len(reqs)} token-exact"
               + (f" (MISMATCH rids {mismatched})" if mismatched else ""))
         assert not mismatched, f"token parity failed for rids {mismatched}"
+    return ServeRun(engine=engine, requests=reqs, params=params,
+                    cache_len=cache_len, wall_s=wall, summary=s, reference=ref)
 
 
 def static_main(cfg, mesh, args) -> None:
@@ -255,7 +282,7 @@ def static_main(cfg, mesh, args) -> None:
     tok_s = args.requests * (args.new_tokens - 1) / max(t_decode, 1e-9)
     print(f"arch={cfg.name} ({cfg.param_count() / 1e6:.0f}M params) "
           f"batch={args.requests} prompt={args.prompt_len} "
-          f"new={args.new_tokens} mesh={args.mesh} [static]")
+          f"new={args.new_tokens} chips={args.chips} [static]")
     print(f"prefill {t_prefill * 1e3:.0f} ms | decode "
           f"{t_decode * 1e3 / max(args.new_tokens - 1, 1):.1f} ms/token "
           f"({tok_s:.1f} tok/s aggregate)")
@@ -263,13 +290,15 @@ def static_main(cfg, mesh, args) -> None:
     print("sample:", gen[0][:12].tolist())
 
 
-def main() -> None:
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="olmo-1b")
     ap.add_argument("--tiny", action="store_true")
     ap.add_argument("--requests", "--batch", type=int, default=4,
                     dest="requests")
     ap.add_argument("--prompt-len", type=int, default=32)
+    ap.add_argument("--min-prompt-len", type=int, default=0,
+                    help="shortest random prompt (0 = half of --prompt-len)")
     ap.add_argument("--new-tokens", type=int, default=16)
     ap.add_argument("--workers", type=int, default=2)
     ap.add_argument("--slots-per-worker", type=int, default=2)
@@ -288,19 +317,24 @@ def main() -> None:
     ap.add_argument("--verify-static", action="store_true",
                     help="check engine tokens against the batch=1 static "
                          "reference, token-for-token")
-    ap.add_argument("--mesh", choices=("debug", "single", "multi"),
-                    default="debug")
+    ap.add_argument("--chips", type=int, default=1,
+                    help="serve on a (1, N) data x model mesh over the "
+                         "first N devices")
     ap.add_argument("--seed", type=int, default=0)
     add_chaos_args(ap)
     add_trace_args(ap)
-    args = ap.parse_args()
+    return ap
+
+
+def main(argv=None) -> None:
+    args = build_parser().parse_args(argv)
     if args.static and (args.chaos != "none" or args.chaos_trace):
         raise SystemExit("--static has no fault tolerance to chaos-test; "
                          "use the continuous engine")
 
     cfg = get_config(args.arch, tiny=args.tiny)
-    mesh = (make_debug_mesh() if args.mesh == "debug" else
-            make_production_mesh(multi_pod=(args.mesh == "multi")))
+    mesh = make_mesh(args.chips)
+    enable_compile_cache()
     supported, why = engine_supported(cfg)
     if not supported:
         raise SystemExit(f"{args.arch}: {why}")

@@ -1,8 +1,8 @@
 """Production training launcher.
 
-On a real TPU slice this runs under the multi-host runtime (one process per
-host; jax.distributed.initialize) with the production mesh; on CPU it runs
-the same code end-to-end with ``--tiny`` configs for validation.
+``--chips N`` trains on a ``(1, N)`` data x model mesh over the host's first
+N devices; on CPU it runs the same code end-to-end with ``--tiny`` configs
+for validation.
 
     PYTHONPATH=src python -m repro.launch.train --arch olmo-1b --tiny \
         --steps 50 --global-batch 8 --seq-len 128 --ckpt-dir /tmp/ckpt
@@ -35,7 +35,7 @@ from repro.distributed.sharding import use_rules
 from repro.distributed.steps import make_train_step
 from repro.ft import (CheckpointStore, DynamicInterval, FaultInjector,
                       PodTrainingCluster, TrainingCoordinator, tree_digest)
-from repro.launch.mesh import make_debug_mesh, make_production_mesh
+from repro.launch.mesh import enable_compile_cache, make_mesh
 from repro.launch.serve import (add_chaos_args, add_trace_args, make_chaos,
                                 make_obs)
 from repro.models import lm
@@ -121,7 +121,7 @@ def cluster_main(cfg, mesh, args) -> None:
               "to the fault-free reference, 0 split-brain divergences")
 
 
-def main() -> None:
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="olmo-1b")
     ap.add_argument("--tiny", action="store_true")
@@ -132,8 +132,9 @@ def main() -> None:
     ap.add_argument("--lr", type=float, default=3e-4)
     ap.add_argument("--ckpt-dir", default="/tmp/repro_ckpt")
     ap.add_argument("--ckpt-gamma-s", type=float, default=5.0)
-    ap.add_argument("--mesh", choices=("debug", "single", "multi"),
-                    default="debug")
+    ap.add_argument("--chips", type=int, default=1,
+                    help="train on a (1, N) data x model mesh over the "
+                         "first N devices")
     ap.add_argument("--inject-mtbf-steps", type=float, default=0.0,
                     help="simulate failures every ~N steps (0 = off)")
     ap.add_argument("--pods", type=int, default=1,
@@ -146,15 +147,11 @@ def main() -> None:
     ap.add_argument("--seed", type=int, default=0)
     add_chaos_args(ap)
     add_trace_args(ap)
-    args = ap.parse_args()
+    return ap
 
-    cfg = get_config(args.arch, tiny=args.tiny)
-    mesh = (make_debug_mesh() if args.mesh == "debug" else
-            make_production_mesh(multi_pod=(args.mesh == "multi")))
-    if args.pods > 1:
-        cluster_main(cfg, mesh, args)
-        return
 
+def train_main(cfg, mesh, args):
+    """Single-pod training under the coordinator; returns its report."""
     ctx = make_obs(args)
     with use_rules(mesh):
         params = lm.init_params(jax.random.key(args.seed), cfg)
@@ -216,11 +213,8 @@ def main() -> None:
           f"({'improved' if last < first else 'NOT improved'}) "
           f"wall={dt:.1f}s ({dt / max(report.steps_completed, 1):.2f}s/step)")
     if profiled is not None:
-        try:
-            profiled.capture_cost(coord.params, coord.opt_state,
-                                  coord.pipeline.batch_at(0))
-        except Exception as e:   # cost_analysis is best-effort per backend
-            print(f"profile: cost_analysis unavailable ({e})")
+        profiled.capture_cost(coord.params, coord.opt_state,
+                              coord.pipeline.batch_at(0))
         prof = profiled.report()
         mean_ms = (prof["mean_s"] or 0.0) * 1e3
         print(f"profile: compile {prof['compile_s'] or 0.0:.2f}s, "
@@ -247,6 +241,18 @@ def main() -> None:
         print(f"chaos-assert OK: {report.steps_completed} steps, "
               f"{report.restores} restores, all losses finite, "
               "committed index clean")
+    return report
+
+
+def main(argv=None) -> None:
+    args = build_parser().parse_args(argv)
+    cfg = get_config(args.arch, tiny=args.tiny)
+    mesh = make_mesh(args.chips)
+    enable_compile_cache()
+    if args.pods > 1:
+        cluster_main(cfg, mesh, args)
+    else:
+        train_main(cfg, mesh, args)
 
 
 if __name__ == "__main__":

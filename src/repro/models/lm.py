@@ -365,9 +365,10 @@ def _kv_shape(cfg, b, s):
     return (b, s, cfg.n_kv_heads, cfg.head_dim)
 
 
-def init_cache(cfg: ModelConfig, batch: int, cache_len: int,
-               dtype=jnp.bfloat16):
-    """Zero cache covering positions [0, cache_len)."""
+def init_cache(cfg: ModelConfig, batch: int, cache_len: int, dtype=None):
+    """Zero cache covering positions [0, cache_len), in the compute dtype
+    unless ``dtype`` says otherwise."""
+    dtype = _compute_dtype(cfg) if dtype is None else dtype
     if cfg.rwkv:
         h = rw.n_heads(cfg)
         L = cfg.n_layers

@@ -10,9 +10,8 @@ metrics registry and (optionally) the span tracer:
   (``jax.block_until_ready`` on the outputs, so async dispatch cannot hide
   the work) into ``profile_step_seconds`` histogram series;
 * **cost analysis** — :meth:`ProfiledFn.capture_cost` lowers + compiles the
-  wrapped function for a concrete arg set and normalizes
-  ``Compiled.cost_analysis()`` via :func:`repro.analysis.hlo.
-  normalize_cost_analysis`, recording FLOPs / bytes-accessed gauges.
+  wrapped function for a concrete arg set and reads
+  ``Compiled.cost_analysis()``, recording FLOPs / bytes-accessed gauges.
 
 :func:`save_profiles` writes the collected profiles as JSON for
 ``benchmarks/roofline.py --profile``, which joins measured step times
@@ -30,8 +29,6 @@ import os
 import time
 
 import jax
-
-from repro.analysis.hlo import normalize_cost_analysis
 
 from .metrics import MetricsRegistry
 from .trace import NULL_TRACER
@@ -102,7 +99,7 @@ class ProfiledFn:
         """Lower + compile for these concrete args and record FLOPs/bytes
         (uses the jit cache's lowering path; one extra compile at most)."""
         lowered = self.fn.lower(*args, **kwargs)
-        cost = normalize_cost_analysis(lowered.compile().cost_analysis())
+        cost = lowered.compile().cost_analysis()
         flops = float(cost.get("flops", 0.0))
         nbytes = float(cost.get("bytes accessed", 0.0))
         self.stats.flops = flops

@@ -65,11 +65,12 @@ vs. CRCH comparison under the stable/normal/unstable failure environments —
 the serving analogue of the paper's Figs. 8-12 wastage-vs-completion
 trade-off.
 """
-from .engine import EngineConfig, ServeEngine, engine_supported
+from .engine import (EngineConfig, ServeEngine, engine_supported,
+                     prefill_inputs, prefill_len)
 from .metrics import ServeMetrics, format_table
 from .queue import (AdmissionQueue, Request, RequestClass, WorkItem,
                     prompt_bucket, request_class, request_features)
-from .reference import greedy_reference
+from .reference import greedy_reference, reference_logits
 from .replicas import (SERVE_ENVIRONMENTS, ReplicaPolicy, WorkerPool,
                        crch_policy, uniform_policy)
 from .snapshot import DecodeSnapshot, SnapshotStore
@@ -91,7 +92,10 @@ __all__ = [
     "engine_supported",
     "format_table",
     "greedy_reference",
+    "prefill_inputs",
+    "prefill_len",
     "prompt_bucket",
+    "reference_logits",
     "request_class",
     "request_features",
     "uniform_policy",

@@ -59,6 +59,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.chaos import faults
+from repro.distributed import params as pshard
+from repro.distributed.sharding import current_mesh
 from repro.distributed.steps import make_prefill_step, make_serve_step
 from repro.ft.interval import DynamicInterval
 from repro.models import lm
@@ -71,7 +73,8 @@ from .replicas import ReplicaPolicy, WorkerPool, uniform_policy
 from .snapshot import (DecodeSnapshot, SnapshotStore, cache_batch_axes,
                        slot_get, slot_set)
 
-__all__ = ["EngineConfig", "ServeEngine", "engine_supported"]
+__all__ = ["EngineConfig", "ServeEngine", "engine_supported",
+           "prefill_inputs", "prefill_len"]
 
 
 def engine_supported(cfg: ModelConfig) -> tuple[bool, str]:
@@ -86,6 +89,41 @@ def engine_supported(cfg: ModelConfig) -> tuple[bool, str]:
     if cfg.rwkv and cfg.d_model % 64 != 0:
         return False, "rwkv d_model must be a multiple of the 64 head size"
     return True, ""
+
+
+def prefill_len(cfg: ModelConfig, prompt_len: int) -> int:
+    """Prefill length of a prompt: its power-of-two bucket, or the exact
+    length for the recurrent families — recurrent state treats every
+    position as a state update, so pad positions are not maskable after the
+    fact."""
+    return prompt_len if (cfg.rwkv or cfg.rglru) else prompt_bucket(prompt_len)
+
+
+def prefill_inputs(cfg: ModelConfig, req: Request, seq: int) -> dict:
+    """Batch=1 prefill inputs: the prompt right-padded to ``seq`` plus the
+    request's side inputs (encoder frames, image embeds)."""
+    padded = np.zeros((1, seq), np.int32)
+    padded[0, :req.prompt_len] = np.asarray(req.prompt, np.int32)
+    batch = {"tokens": jnp.asarray(padded)}
+    if cfg.is_encdec:
+        batch["frames"] = jnp.asarray(np.asarray(req.frames, np.float32))[None]
+    if cfg.n_image_tokens:
+        batch["image_embeds"] = jnp.asarray(
+            np.asarray(req.image_embeds, np.float32))[None]
+    return batch
+
+
+def init_slot_cache(cfg: ModelConfig, slots: int, cache_len: int):
+    """Zero decode cache for ``slots`` slots.  Inside a ``use_rules(mesh)``
+    scope it takes the mesh layout of ``params.cache_specs``: slots on
+    ``data``, the KV sequence on ``model`` (the ``kv_seq`` rule)."""
+    cache = lm.init_cache(cfg, slots, cache_len)
+    mesh = current_mesh()
+    if mesh is None:
+        return cache
+    specs = pshard.cache_specs(cache, cfg, mesh)
+    return jax.device_put(cache, jax.tree.map(
+        lambda s: jax.sharding.NamedSharding(mesh, s), specs))
 
 
 @dataclasses.dataclass
@@ -110,6 +148,10 @@ class EngineConfig:
     # retry_after hint once queue depth crosses this bound, so the queue
     # stays bounded under sustained capacity loss (None = unbounded)
     max_queue_depth: int | None = None
+    # keep every logits row the engine computes in ``logit_log`` as
+    # (rid, token index, row) — for checks against a reference; each decode
+    # tick then copies the whole (slots, V) logits to the host
+    record_logits: bool = False
 
 
 @dataclasses.dataclass
@@ -169,7 +211,7 @@ class ServeEngine:
             prior_mtbf_s=self.ecfg.prior_mtbf_steps)
 
         cache_len = self.ecfg.cache_len
-        self.cache = lm.init_cache(cfg, pool.n_slots, cache_len)
+        self.cache = init_slot_cache(cfg, pool.n_slots, cache_len)
         self.axes = cache_batch_axes(cfg, cache_len)
         self._serve = jax.jit(make_serve_step(cfg, cache_axes=self.axes),
                               donate_argnums=(1,))
@@ -185,6 +227,7 @@ class ServeEngine:
                              self.axes)),
             donate_argnums=(0,))
         self._prefill_fns: dict[int, callable] = {}
+        self.logit_log: list[tuple[int, int, np.ndarray]] = []
 
     # -- submission ----------------------------------------------------------
     def submit(self, req: Request) -> int:
@@ -380,18 +423,6 @@ class ServeEngine:
             self._prefill_fns[seq] = fn
         return fn
 
-    def _prefill_batch(self, req: Request, seq: int) -> dict:
-        padded = np.zeros((1, seq), np.int32)
-        padded[0, :req.prompt_len] = np.asarray(req.prompt, np.int32)
-        batch = {"tokens": jnp.asarray(padded)}
-        if self.cfg.is_encdec:
-            batch["frames"] = jnp.asarray(
-                np.asarray(req.frames, np.float32))[None]
-        if self.cfg.n_image_tokens:
-            batch["image_embeds"] = jnp.asarray(
-                np.asarray(req.image_embeds, np.float32))[None]
-        return batch
-
     def _start(self, slot: _Slot, item: WorkItem, t: int) -> None:
         req = item.req
         slot.busy = True
@@ -422,18 +453,17 @@ class ServeEngine:
         else:
             p = req.prompt_len
             offset = self.cfg.n_image_tokens or 0
-            # recurrent state treats every position as a state update, so pad
-            # positions are not maskable after the fact: prefill at the exact
-            # prompt length instead of the padded bucket
-            exact = self.cfg.rwkv or self.cfg.rglru
-            seq = p if exact else prompt_bucket(p)
+            seq = prefill_len(self.cfg, p)
             with self.tracer.span("serve.prefill", rid=req.rid, seq=seq,
                                   step=t):
                 logits, row1 = self._prefill(seq)(
-                    self.params, self._prefill_batch(req, seq),
+                    self.params, prefill_inputs(self.cfg, req, seq),
                     jnp.asarray([offset + p - 1], jnp.int32))
             self.cache = self._insert(self.cache, slot.sid, row1)
-            tok = int(np.argmax(np.asarray(logits[0])))
+            row = np.asarray(logits[0])
+            tok = int(np.argmax(row))
+            if self.ecfg.record_logits:
+                self.logit_log.append((req.rid, 0, row))
             slot.pos = offset + p
             slot.tokens = [tok]
             slot.last_token = tok
@@ -460,12 +490,15 @@ class ServeEngine:
             live[s.sid] = s.busy and s.sid not in stalled
         with self.tracer.span("serve.decode", track="serve", step=t,
                               live=len(busy), stalled=len(stalled)):
-            nxt, _, self.cache = self._serve(
+            nxt, logits, self.cache = self._serve(
                 self.params, self.cache, jnp.asarray(toks),
                 jnp.asarray(poss), jnp.asarray(live))
         nxt = np.asarray(nxt)
+        rows = np.asarray(logits) if self.ecfg.record_logits else None
         for s in busy:
             tok = int(nxt[s.sid, 0])
+            if rows is not None:
+                self.logit_log.append((s.rid, len(s.tokens), rows[s.sid]))
             s.tokens.append(tok)
             s.last_token = tok
             s.pos += 1

@@ -10,7 +10,7 @@ from repro.distributed import params as pshard
 from repro.distributed.sharding import (DEFAULT_RULES, constrain,
                                         logical_to_spec, use_rules)
 from repro.distributed.steps import make_train_step
-from repro.launch.mesh import make_debug_mesh
+from repro.launch.mesh import make_mesh
 from repro.launch.shapes import SHAPES, cell_supported, input_specs
 from repro.models import lm
 from repro.optim import adamw_init
@@ -18,7 +18,7 @@ from repro.optim import adamw_init
 
 @pytest.fixture(scope="module")
 def mesh():
-    return make_debug_mesh()
+    return make_mesh(1)
 
 
 def _abstract(arch):

@@ -9,7 +9,8 @@ from repro.models import lm
 from repro.serve import (AdmissionQueue, EngineConfig, Request, ServeEngine,
                          ServeMetrics, WorkItem, WorkerPool, crch_policy,
                          engine_supported, greedy_reference, prompt_bucket,
-                         request_class, request_features, uniform_policy)
+                         reference_logits, request_class, request_features,
+                         uniform_policy)
 from repro.serve.snapshot import cache_batch_axes, slot_get, slot_set
 
 
@@ -294,6 +295,20 @@ def test_engine_token_parity_with_static_reference(arch):
                            q_chunk=32)
     for r in reqs:
         assert engine.output(r.rid) == ref[r.rid], r.rid
+
+
+def test_reference_logits_fed_greedy_tokens_reproduce_them():
+    """Fed the greedy tokens, the static path's logits rows are the ones
+    greedy decoding chose from: one row per token, argmax == that token."""
+    cfg = get_config("olmo-1b", tiny=True)
+    params = lm.init_params(jax.random.key(0), cfg)
+    reqs = [_req(i, 5 + 2 * i, 8, seed=13, cfg=cfg) for i in range(3)]
+    cache_len = _cache_len_for(cfg, reqs)
+    greedy = greedy_reference(params, cfg, reqs, cache_len, q_chunk=32)
+    rows = reference_logits(params, cfg, reqs, cache_len, greedy, q_chunk=32)
+    for r in reqs:
+        assert rows[r.rid].shape == (r.max_new_tokens, cfg.vocab_size)
+        assert rows[r.rid].argmax(-1).tolist() == greedy[r.rid], r.rid
 
 
 def test_engine_rwkv_failure_resume_matches_failure_free():

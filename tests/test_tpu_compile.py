@@ -1,0 +1,111 @@
+"""Compile the main path for a described TPU v5e chip, with no chip attached.
+
+The TPU compiler refuses what interpret mode and the CPU backend accept: a
+program that does not fit the chip's memory, a kernel slice not aligned to
+the tiling.  These compiles guard the serve step, prefill and the Pallas
+distance kernel at the sizes ``chip_smoke.py`` runs, at no chip time.
+
+The topology is described inside a fixture, never at import: only one
+process at a time may load the TPU library, and every test worker imports
+this file.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.configs import get_config
+from repro.distributed.steps import make_prefill_step, make_serve_step
+from repro.kernels.pairwise_affinity import ops as pa_ops
+from repro.models import lm
+from repro.serve.snapshot import cache_batch_axes
+
+V5E_HBM_BYTES = 16 * 2 ** 30
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def no_compile_cache():
+    """A compile for a described chip is written to the persistent cache but
+    cannot be read back without the chip: keep the cache off around them."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+    cc.reset_cache()
+
+
+def _on(sharding, tree):
+    return jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding),
+        tree)
+
+
+@pytest.fixture(scope="module")
+def olmo(one_chip):
+    cfg = get_config("olmo-1b")
+    params = jax.eval_shape(lambda: lm.init_params(jax.random.key(0), cfg))
+    return cfg, _on(one_chip, params)
+
+
+def _fits(compiled) -> int:
+    m = compiled.memory_analysis()
+    used = m.argument_size_in_bytes + m.temp_size_in_bytes
+    assert used < V5E_HBM_BYTES, f"{used / 2 ** 30:.2f} GiB > 16 GiB"
+    return used
+
+
+def test_olmo_masked_serve_step_fits_one_chip(olmo, one_chip,
+                                              no_compile_cache):
+    cfg, params = olmo
+    slots, cache_len = 8, 2048
+    cache = _on(one_chip, jax.eval_shape(
+        lambda: lm.init_cache(cfg, slots, cache_len)))
+    step = jax.jit(make_serve_step(cfg, cache_axes=cache_batch_axes(
+        cfg, cache_len)), donate_argnums=(1,))
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = step.lower(params, cache, sds((slots, 1), jnp.int32),
+                          sds((slots,), jnp.int32),
+                          sds((slots,), jnp.bool_)).compile()
+    _fits(compiled)
+
+
+def test_olmo_prefill_fits_one_chip(olmo, one_chip, no_compile_cache):
+    cfg, params = olmo
+    bucket, cache_len = 512, 2048
+    prefill = jax.jit(make_prefill_step(cfg, cache_len, q_chunk=64,
+                                        with_last_idx=True))
+    tokens = jax.ShapeDtypeStruct((1, bucket), jnp.int32, sharding=one_chip)
+    last = jax.ShapeDtypeStruct((1,), jnp.int32, sharding=one_chip)
+    compiled = prefill.lower(params, {"tokens": tokens}, last).compile()
+    _fits(compiled)
+
+
+def test_pairwise_distance_kernel_compiles_for_the_chip(one_chip,
+                                                        no_compile_cache):
+    # the paper's largest workflow: 700 tasks x 10 task features
+    pts = jax.ShapeDtypeStruct((700, 10), jnp.float32, sharding=one_chip)
+    compiled = jax.jit(pa_ops.pairwise_distance).lower(pts).compile()
+    assert "tpu_custom_call" in compiled.as_text()
