@@ -16,7 +16,14 @@ Two properties the fault-tolerance layers rely on:
   Instrumented code therefore never needs ``if tracer is not None`` guards;
 * **deterministic timestamps on demand** — the clock is injectable
   (``clock=``), so tests drive spans with a fake counter and dumps become
-  byte-stable.
+  byte-stable;
+* **a second sink on the profiler's clock** — ``annotate=`` takes a
+  context-manager factory called with a span's name (for example
+  ``jax.profiler.TraceAnnotation``).  Every span then also opens and closes
+  ``annotate(name)``, so a profile taken while the tracer runs holds each
+  span on the same clock as the device's operations.  Only the name goes
+  there; attributes stay in the recorder.  The factory is injected, so this
+  module imports no JAX.
 
 Span names form the witness vocabulary of the fault taxonomy (see the
 Observability section of ROADMAP.md): every recovery path emits a
@@ -53,7 +60,7 @@ class Span:
     """One live span.  Use as a context manager; emitted on exit."""
 
     __slots__ = ("tracer", "name", "track", "attrs", "span_id", "parent_id",
-                 "t0", "t1")
+                 "t0", "t1", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, track: str,
                  attrs: dict, span_id: int, parent_id: int | None):
@@ -65,6 +72,7 @@ class Span:
         self.parent_id = parent_id
         self.t0 = 0.0
         self.t1 = 0.0
+        self._ann = None
 
     def set(self, **attrs) -> "Span":
         """Attach attributes mid-span (e.g. an outcome discovered late)."""
@@ -72,12 +80,19 @@ class Span:
         return self
 
     def __enter__(self) -> "Span":
+        annotate = self.tracer.annotate
+        if annotate is not None:
+            self._ann = annotate(self.name)
+            self._ann.__enter__()
         self.t0 = self.tracer.clock()
         self.tracer._stack.append(self)
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         self.t1 = self.tracer.clock()
+        if self._ann is not None:
+            self._ann.__exit__(exc_type, exc, tb)
+            self._ann = None
         stack = self.tracer._stack
         if stack and stack[-1] is self:
             stack.pop()
@@ -95,10 +110,13 @@ class Tracer:
     """Emits spans/events into a recorder.  Disabled = one-branch no-op."""
 
     def __init__(self, recorder=None, *, clock=time.monotonic,
-                 enabled: bool = True):
+                 enabled: bool = True, annotate=None):
         self.recorder = recorder
         self.clock = clock
         self.enabled = enabled and recorder is not None
+        # context-manager factory taking a span name; opened around every
+        # span (a disabled tracer opens no span, so never calls it)
+        self.annotate = annotate
         self._stack: list[Span] = []
         self._next_id = 1
 

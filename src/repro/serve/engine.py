@@ -48,6 +48,18 @@ provably cannot meet its deadline even if admitted this very tick is shed,
 lowest request class (priority, then slack) first.  A request with a live
 copy past its first token is *never* shed — the ``past_first_token_drops``
 metric is the tripwire proving it.
+
+Tracing: every tick is a ``serve.tick`` span whose children cover its
+phases — ``serve.tick.faults`` (chaos and worker failures),
+``serve.tick.shed``, ``serve.tick.admit`` (holding ``serve.prefill`` and
+``serve.restore``), ``serve.decode`` and ``serve.tick.snapshots`` (one
+``serve.snapshot.take`` per snapshot: ``.copy`` off the device, then
+``.digest``).  A span that waits for the device closes only after the host
+holds the result, inside a ``*.wait`` child (``serve.decode.wait``,
+``serve.prefill.wait``), so host work and device waits can be told apart.
+With a tracer whose spans are also profiler annotations the phases line up
+with the device's operations.  ``serve.start`` reports the queue wait of
+each copy that takes a slot.
 """
 from __future__ import annotations
 
@@ -203,6 +215,7 @@ class ServeEngine:
         self.active: dict[int, set[int]] = {}      # rid -> live slot ids
         self.completed: dict[int, list[int]] = {}  # rid -> delivered tokens
         self.requests: dict[int, Request] = {}
+        self._started: set[int] = set()   # rids a copy of which took a slot
         self._completed_order: collections.deque[int] = collections.deque()
         self.step_no = 0
         self.interval = DynamicInterval(
@@ -215,17 +228,23 @@ class ServeEngine:
         self.axes = cache_batch_axes(cfg, cache_len)
         self._serve = jax.jit(make_serve_step(cfg, cache_axes=self.axes),
                               donate_argnums=(1,))
-        self._get = jax.jit(
-            lambda cache, sid: slot_get(cache, self.axes, sid))
-        self._set = jax.jit(
-            lambda cache, sid, row: slot_set(cache, self.axes, sid, row),
-            donate_argnums=(0,))
-        self._insert = jax.jit(
-            lambda cache, sid, row1: slot_set(
-                cache, self.axes, sid,
-                jax.tree.map(lambda l, a: jnp.squeeze(l, a), row1,
-                             self.axes)),
-            donate_argnums=(0,))
+        axes = self.axes
+
+        # named, so each compiles to its own module (jit_slot_read, ...)
+        # and a profile tells the three apart
+        def slot_read(cache, sid):
+            return slot_get(cache, axes, sid)
+
+        def slot_write(cache, sid, row):
+            return slot_set(cache, axes, sid, row)
+
+        def cache_insert(cache, sid, row1):
+            row = jax.tree.map(lambda l, a: jnp.squeeze(l, a), row1, axes)
+            return slot_set(cache, axes, sid, row)
+
+        self._get = jax.jit(slot_read)
+        self._set = jax.jit(slot_write, donate_argnums=(0,))
+        self._insert = jax.jit(cache_insert, donate_argnums=(0,))
         self._prefill_fns: dict[int, callable] = {}
         self.logit_log: list[tuple[int, int, np.ndarray]] = []
 
@@ -251,8 +270,9 @@ class ServeEngine:
                 f"image embeds")
         self.metrics.register(req)
         rep = self.policy.rep_for(req)
+        now = self.tracer.clock()
         retry_after = self.queue.admit(
-            [WorkItem(req, copy_id=k) for k in range(rep)])
+            [WorkItem(req, copy_id=k, enqueued_at=now) for k in range(rep)])
         if retry_after is not None:
             self.rejected[req.rid] = retry_after
             self.metrics.mark_rejected(req.rid, self.step_no, retry_after)
@@ -331,7 +351,8 @@ class ServeEngine:
             snap = (self.store.get(rid)
                     if self.ecfg.snapshots_enabled else None)
             self.queue.submit(WorkItem(self.requests[rid], copy_id=0,
-                                       snapshot=snap, is_resubmission=True))
+                                       snapshot=snap, is_resubmission=True,
+                                       enqueued_at=self.tracer.clock()))
             self.metrics.resubmissions += 1
             self.tracer.recovery("host_crash", rid=rid,
                                  from_snapshot=snap is not None)
@@ -425,6 +446,7 @@ class ServeEngine:
 
     def _start(self, slot: _Slot, item: WorkItem, t: int) -> None:
         req = item.req
+        tr = self.tracer
         slot.busy = True
         slot.rid = req.rid
         slot.copy_id = item.copy_id
@@ -432,35 +454,46 @@ class ServeEngine:
         slot.req = req
         slot.since_snapshot = 0
         self.active.setdefault(req.rid, set()).add(slot.sid)
+        first = req.rid not in self._started
+        self._started.add(req.rid)
         snap: DecodeSnapshot | None = item.snapshot
-        if snap is not None and not self.store.verify(snap):
-            # checksum mismatch: quarantine the snapshot and fall back to a
-            # full re-prefill — never resume from garbage decode state
-            self.metrics.snapshot_restore_failures += 1
-            self.store.drop(snap.rid)
-            self.tracer.recovery("snapshot_corrupt", rid=req.rid,
-                                 action="reprefill")
-            snap = None
+        tr.event("serve.start", rid=req.rid, copy_id=item.copy_id,
+                 resumed=snap is not None, first=first,
+                 waited_s=tr.clock() - item.enqueued_at)
         if snap is not None:
-            row = jax.tree.map(jnp.asarray, snap.cache_row)
-            self.cache = self._set(self.cache, slot.sid, row)
+            with tr.span("serve.restore", rid=req.rid, step=t):
+                with tr.span("serve.restore.verify"):
+                    intact = self.store.verify(snap)
+                if intact:
+                    with tr.span("serve.restore.write"):
+                        row = jax.tree.map(jnp.asarray, snap.cache_row)
+                        self.cache = self._set(self.cache, slot.sid, row)
+            if not intact:
+                # checksum mismatch: quarantine the snapshot and fall back to
+                # a full re-prefill — never resume from garbage decode state
+                self.metrics.snapshot_restore_failures += 1
+                self.store.drop(snap.rid)
+                tr.recovery("snapshot_corrupt", rid=req.rid,
+                            action="reprefill")
+                snap = None
+        if snap is not None:
             slot.pos = snap.pos
             slot.tokens = list(snap.tokens)
             slot.last_token = snap.last_token
             self.metrics.restores += 1
-            self.tracer.event("serve.resume", rid=req.rid, pos=snap.pos,
-                              banked=len(snap.tokens))
+            tr.event("serve.resume", rid=req.rid, pos=snap.pos,
+                     banked=len(snap.tokens))
         else:
             p = req.prompt_len
             offset = self.cfg.n_image_tokens or 0
             seq = prefill_len(self.cfg, p)
-            with self.tracer.span("serve.prefill", rid=req.rid, seq=seq,
-                                  step=t):
+            with tr.span("serve.prefill", rid=req.rid, seq=seq, step=t):
                 logits, row1 = self._prefill(seq)(
                     self.params, prefill_inputs(self.cfg, req, seq),
                     jnp.asarray([offset + p - 1], jnp.int32))
-            self.cache = self._insert(self.cache, slot.sid, row1)
-            row = np.asarray(logits[0])
+                self.cache = self._insert(self.cache, slot.sid, row1)
+                with tr.span("serve.prefill.wait"):
+                    row = np.asarray(logits[0])
             tok = int(np.argmax(row))
             if self.ecfg.record_logits:
                 self.logit_log.append((req.rid, 0, row))
@@ -481,32 +514,36 @@ class ServeEngine:
                 if s.busy and s.sid not in stalled]
         if not busy:
             return
-        toks = np.zeros((len(self.slots), 1), np.int32)
-        poss = np.zeros((len(self.slots),), np.int32)
-        live = np.zeros((len(self.slots),), bool)
-        for s in self.slots:
-            toks[s.sid, 0] = s.last_token
-            poss[s.sid] = s.pos
-            live[s.sid] = s.busy and s.sid not in stalled
-        with self.tracer.span("serve.decode", track="serve", step=t,
-                              live=len(busy), stalled=len(stalled)):
+        tr = self.tracer
+        with tr.span("serve.decode", step=t, live=len(busy),
+                     stalled=len(stalled)):
+            toks = np.zeros((len(self.slots), 1), np.int32)
+            poss = np.zeros((len(self.slots),), np.int32)
+            live = np.zeros((len(self.slots),), bool)
+            for s in self.slots:
+                toks[s.sid, 0] = s.last_token
+                poss[s.sid] = s.pos
+                live[s.sid] = s.busy and s.sid not in stalled
             nxt, logits, self.cache = self._serve(
                 self.params, self.cache, jnp.asarray(toks),
                 jnp.asarray(poss), jnp.asarray(live))
-        nxt = np.asarray(nxt)
-        rows = np.asarray(logits) if self.ecfg.record_logits else None
-        for s in busy:
-            tok = int(nxt[s.sid, 0])
-            if rows is not None:
-                self.logit_log.append((s.rid, len(s.tokens), rows[s.sid]))
-            s.tokens.append(tok)
-            s.last_token = tok
-            s.pos += 1
-            s.since_snapshot += 1
-            self.metrics.decode_tokens += 1
-        for s in busy:
-            if s.busy and len(s.tokens) >= s.max_new:
-                self._finish(s, t)
+            with tr.span("serve.decode.wait"):
+                nxt = np.asarray(nxt)
+                rows = (np.asarray(logits) if self.ecfg.record_logits
+                        else None)
+            for s in busy:
+                tok = int(nxt[s.sid, 0])
+                if rows is not None:
+                    self.logit_log.append((s.rid, len(s.tokens),
+                                           rows[s.sid]))
+                s.tokens.append(tok)
+                s.last_token = tok
+                s.pos += 1
+                s.since_snapshot += 1
+                self.metrics.decode_tokens += 1
+            for s in busy:
+                if s.busy and len(s.tokens) >= s.max_new:
+                    self._finish(s, t)
 
     def _finish(self, slot: _Slot, t: int) -> None:
         rid = slot.rid
@@ -525,6 +562,7 @@ class ServeEngine:
             old = self._completed_order.popleft()
             self.completed.pop(old, None)
             self.requests.pop(old, None)
+            self._started.discard(old)
             self.store.drop(old)
 
     # -- snapshot cadence (Lemma 3.1 online) ---------------------------------
@@ -537,29 +575,44 @@ class ServeEngine:
         if not self.ecfg.snapshots_enabled:
             return
         cadence = self._snapshot_every()
+        tr = self.tracer
         for s in self.slots:
-            if s.busy and s.since_snapshot >= cadence:
-                row = jax.device_get(self._get(self.cache, s.sid))
-                self.store.save(DecodeSnapshot(
+            if not (s.busy and s.since_snapshot >= cadence):
+                continue
+            with tr.span("serve.snapshot.take", rid=s.rid, step=t):
+                with tr.span("serve.snapshot.copy"):
+                    row = jax.device_get(self._get(self.cache, s.sid))
+                snap = DecodeSnapshot(
                     rid=s.rid, pos=s.pos, tokens=list(s.tokens),
-                    last_token=s.last_token, cache_row=row, step=t))
+                    last_token=s.last_token, cache_row=row, step=t)
+                with tr.span("serve.snapshot.digest"):
+                    self.store.save(snap)
                 self.metrics.snapshots += 1
+                self.metrics.snapshot_bytes += sum(
+                    leaf.nbytes for leaf in jax.tree.leaves(row))
                 self.metrics.snapshot_overhead_tokens += \
                     self.ecfg.snapshot_gamma
-                self.tracer.event("serve.snapshot", rid=s.rid, pos=s.pos,
-                                  step=t)
+                tr.event("serve.snapshot", rid=s.rid, pos=s.pos, step=t)
                 s.since_snapshot = 0
 
     # -- main loop -----------------------------------------------------------
     def step(self) -> None:
         t = self.step_no
-        if self.chaos is not None:
-            self._apply_chaos(t)
-        self._on_worker_failures(t)
-        self._shed(t)
-        self._admit(t)
-        self._decode(t)
-        self._take_snapshots(t)
+        tr = self.tracer
+        with tr.span("serve.tick", step=t,
+                     live=sum(s.busy for s in self.slots),
+                     queued=len(self.queue)):
+            with tr.span("serve.tick.faults"):
+                if self.chaos is not None:
+                    self._apply_chaos(t)
+                self._on_worker_failures(t)
+            with tr.span("serve.tick.shed"):
+                self._shed(t)
+            with tr.span("serve.tick.admit"):
+                self._admit(t)
+            self._decode(t)
+            with tr.span("serve.tick.snapshots"):
+                self._take_snapshots(t)
         self.step_no = t + 1
 
     def pending(self) -> bool:

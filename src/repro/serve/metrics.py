@@ -17,13 +17,15 @@ Online counterparts of the simulator metrics in ``repro.core.metrics``
   steps (the serving analogue of workflow success rate x 1/TET).
 
 Since the ``repro.obs`` unification the counters live in a
-:class:`~repro.obs.metrics.MetricsRegistry` as three labeled families —
-``serve_tokens_total{kind=...}``, ``serve_events_total{kind=...}`` and
-``serve_drops_total{reason=...}`` — and :class:`ServeMetrics` is a thin
-compatibility shim: the legacy attribute names (``metrics.failures += 1``,
-``metrics.rejected_on_arrival``) read and write the corresponding labeled
-series via ``__getattr__``/``__setattr__``, so the engine and every
-existing test keep working unchanged while exporters see one registry.
+:class:`~repro.obs.metrics.MetricsRegistry` as four labeled families —
+``serve_tokens_total{kind=...}``, ``serve_events_total{kind=...}``,
+``serve_drops_total{reason=...}`` and ``serve_bytes_total{kind=...}``
+(bytes copied from the device to the host, such as decode snapshots) —
+and :class:`ServeMetrics` is a thin compatibility shim: the legacy
+attribute names (``metrics.failures += 1``, ``metrics.rejected_on_arrival``)
+read and write the corresponding labeled series via
+``__getattr__``/``__setattr__``, so the engine and every existing test keep
+working unchanged while exporters see one registry.
 Pass a shared registry to pool serving series with the rest of a run.
 """
 from __future__ import annotations
@@ -91,6 +93,7 @@ class ServeMetrics:
         # tripwire: a request past its first token must never be dropped
         "past_first_token_drops": ("serve_drops_total",
                                    {"reason": "past_first_token"}),
+        "snapshot_bytes": ("serve_bytes_total", {"kind": "snapshot"}),
     }
 
     def __init__(self, registry: MetricsRegistry | None = None) -> None:
@@ -107,6 +110,10 @@ class ServeMetrics:
             "serve_drops_total": self.registry.counter(
                 "serve_drops_total",
                 "request/copy drops by reason", ("reason",)),
+            "serve_bytes_total": self.registry.counter(
+                "serve_bytes_total",
+                "bytes copied from the device to the host, by kind",
+                ("kind",)),
         }
 
     def __getattr__(self, name):

@@ -138,13 +138,16 @@ class WorkItem:
     (``copy_id`` 0..r-1) that must land on distinct workers — the paper's
     Algorithm 1 ``repCount`` over-provisioning.  A resubmission (all copies
     failed, Algorithm 3 steps 14-15/25-26) re-enters the queue as a new item
-    carrying the request's last decode snapshot, if any.
+    carrying the request's last decode snapshot, if any.  ``enqueued_at``
+    is when the item entered the queue, on the engine tracer's clock: the
+    queue wait a ``serve.start`` event reports runs from it.
     """
 
     req: Request
     copy_id: int = 0
     snapshot: object | None = None      # repro.serve.snapshot.DecodeSnapshot
     is_resubmission: bool = False
+    enqueued_at: float = 0.0
 
 
 class AdmissionQueue:

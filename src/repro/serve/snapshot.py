@@ -90,10 +90,6 @@ class DecodeSnapshot:
     step: int                   # engine step at which it was taken
     checksum: str = ""          # content hash set by SnapshotStore.save
 
-    def nbytes(self) -> int:
-        return int(sum(np.asarray(l).nbytes
-                       for l in jax.tree.leaves(self.cache_row)))
-
 
 def snapshot_digest(snap: DecodeSnapshot) -> str:
     """Content hash over decode registers + tokens + every cache-row leaf."""
@@ -112,15 +108,11 @@ class SnapshotStore:
 
     def __init__(self) -> None:
         self._by_rid: dict[int, DecodeSnapshot] = {}
-        self.saved = 0
-        self.bytes_written = 0
         self.corrupted = 0
 
     def save(self, snap: DecodeSnapshot) -> None:
         snap.checksum = snapshot_digest(snap)
         self._by_rid[snap.rid] = snap
-        self.saved += 1
-        self.bytes_written += snap.nbytes()
 
     def get(self, rid: int) -> DecodeSnapshot | None:
         return self._by_rid.get(rid)

@@ -76,6 +76,54 @@ def test_span_nesting_parent_ids_and_error_attr():
     assert validate_events(events) == []
 
 
+class FakeAnnotation:
+    """Context-manager factory that logs each enter and exit by name."""
+
+    def __init__(self):
+        self.log = []
+
+    def __call__(self, name):
+        log = self.log
+
+        class _Ann:
+            def __enter__(self):
+                log.append(("enter", name))
+
+            def __exit__(self, *exc):
+                log.append(("exit", name))
+                return False
+        return _Ann()
+
+
+def test_annotate_sink_follows_span_names_and_nesting():
+    ann = FakeAnnotation()
+    rec = FlightRecorder(64, clock=FakeClock())
+    tr = Tracer(rec, clock=FakeClock(), annotate=ann)
+    with tr.span("outer", step=1):
+        with tr.span("inner", k=2):
+            tr.event("tick")          # point events open no annotation
+        with tr.span("second"):
+            pass
+    with pytest.raises(RuntimeError):
+        with tr.span("boom"):
+            raise RuntimeError("x")
+    assert ann.log == [("enter", "outer"), ("enter", "inner"),
+                       ("exit", "inner"), ("enter", "second"),
+                       ("exit", "second"), ("exit", "outer"),
+                       ("enter", "boom"), ("exit", "boom")]
+    # the recorder's spans close in the same order; attributes stay there
+    spans = [e for e in rec.snapshot() if e["type"] == "span"]
+    assert [e["name"] for e in spans] == ["inner", "second", "outer",
+                                          "boom"]
+    assert spans[0]["attrs"] == {"k": 2}
+    # disabled tracers open no span, so never call the factory
+    for off in (NULL_TRACER, Tracer(None, annotate=ann),
+                Tracer(rec, enabled=False, annotate=ann)):
+        with off.span("x"):
+            off.event("y")
+    assert len(ann.log) == 8
+
+
 def test_complete_bypasses_stack():
     rec = FlightRecorder(16, clock=FakeClock())
     tr = Tracer(rec, clock=FakeClock())

@@ -1,11 +1,14 @@
 """Tests for repro.serve: queue, snapshots, CRCH routing, and the engine's
 failure-determinism guarantee."""
+import collections
+
 import jax
 import numpy as np
 import pytest
 
 from repro.configs import get_config
 from repro.models import lm
+from repro.obs import FlightRecorder, Tracer
 from repro.serve import (AdmissionQueue, EngineConfig, Request, ServeEngine,
                          ServeMetrics, WorkItem, WorkerPool, crch_policy,
                          engine_supported, greedy_reference, prompt_bucket,
@@ -154,17 +157,22 @@ def _cache_len_for(cfg, reqs):
     return cache_len
 
 
-def _run_engine(cfg, params, reqs, *, fail=None, snapshot_lambda=4,
-                policy=None, retain_completed=4096):
+def _engine(cfg, params, reqs, *, fail=None, snapshot_lambda=4,
+            policy=None, retain_completed=4096, tracer=None):
     cache_len = _cache_len_for(cfg, reqs)
     pool = WorkerPool(2, 2, mtbf_steps=0.0, mttr_steps=6, seed=0)
     if fail is not None:
         pool.force_failure(fail[0], wid=fail[1])
-    engine = ServeEngine(
+    return ServeEngine(
         cfg, EngineConfig(cache_len=cache_len, q_chunk=32,
                           snapshot_lambda=snapshot_lambda,
                           retain_completed=retain_completed),
-        pool=pool, policy=policy or uniform_policy(1), params=params)
+        pool=pool, policy=policy or uniform_policy(1), params=params,
+        tracer=tracer)
+
+
+def _run_engine(cfg, params, reqs, **kw):
+    engine = _engine(cfg, params, reqs, **kw)
     for r in reqs:
         engine.submit(r)
     engine.run(max_steps=2_000)
@@ -325,3 +333,153 @@ def test_engine_rwkv_failure_resume_matches_failure_free():
     assert faulty.metrics.resubmissions >= 1
     for rid in clean.completed:
         assert clean.completed[rid] == faulty.completed[rid], rid
+
+
+# -------------------------------------------------------------- tracing ----
+
+class TickClock:
+    """Strictly increasing fake clock: every reading is one tick later."""
+
+    def __init__(self):
+        self.t = 0.0
+
+    def __call__(self):
+        self.t += 1.0
+        return self.t
+
+
+# each engine span and the span it must open inside
+SPAN_PARENT = {
+    "serve.tick.faults": "serve.tick",
+    "serve.tick.shed": "serve.tick",
+    "serve.tick.admit": "serve.tick",
+    "serve.decode": "serve.tick",
+    "serve.tick.snapshots": "serve.tick",
+    "serve.prefill": "serve.tick.admit",
+    "serve.restore": "serve.tick.admit",
+    "serve.restore.verify": "serve.restore",
+    "serve.restore.write": "serve.restore",
+    "serve.decode.wait": "serve.decode",
+    "serve.prefill.wait": "serve.prefill",
+    "serve.snapshot.take": "serve.tick.snapshots",
+    "serve.snapshot.copy": "serve.snapshot.take",
+    "serve.snapshot.digest": "serve.snapshot.take",
+}
+
+
+def _traced_failure_run(tiny_setup, **kw):
+    cfg, params = tiny_setup
+    reqs = [_req(i, 8 + 3 * i, 16, vocab=cfg.vocab_size, seed=3)
+            for i in range(4)]
+    rec = FlightRecorder(1 << 16)
+    tracer = Tracer(rec, clock=TickClock(), **kw)
+    engine = _run_engine(cfg, params, reqs, fail=(9, 0), tracer=tracer)
+    return engine, reqs, rec.snapshot()
+
+
+def test_engine_tick_spans_nest_under_their_parents(tiny_setup):
+    engine, reqs, events = _traced_failure_run(tiny_setup)
+    spans = {e["span_id"]: e for e in events if e["type"] == "span"}
+    names = {e["name"] for e in spans.values()}
+    # the run exercises every phase: failures, restores and snapshots
+    assert set(SPAN_PARENT) | {"serve.tick"} <= names
+    ticks = [e for e in spans.values() if e["name"] == "serve.tick"]
+    assert len(ticks) == engine.step_no
+    assert all(e["parent_id"] is None for e in ticks)
+    for e in spans.values():
+        parent = spans[e["parent_id"]] if e["parent_id"] else None
+        want = SPAN_PARENT.get(e["name"])
+        if want is None:
+            continue
+        assert parent is not None and parent["name"] == want, e
+        assert parent["t0"] < e["t0"] <= e["t1"] < parent["t1"], e
+    # serve.start: one per copy that takes a slot, one first per request
+    starts = [e["attrs"] for e in events if e["name"] == "serve.start"]
+    assert all(a["waited_s"] >= 0 for a in starts)
+    firsts = collections.Counter(a["rid"] for a in starts if a["first"])
+    assert firsts == collections.Counter(r.rid for r in reqs)
+    resumed = [a for a in starts if a["resumed"]]
+    assert resumed and not any(a["first"] for a in resumed)
+    assert len(resumed) == engine.metrics.restores
+    # snapshot bytes: one slot row per snapshot
+    row = jax.device_get(engine._get(engine.cache, 0))
+    per = sum(leaf.nbytes for leaf in jax.tree.leaves(row))
+    assert engine.metrics.snapshot_bytes == per * engine.metrics.snapshots
+    assert engine.metrics.registry.value(
+        "serve_bytes_total", kind="snapshot") == engine.metrics.snapshot_bytes
+
+
+class _Probe:
+    """A device result whose host conversion reads the tracer's clock."""
+
+    def __init__(self, value, clock, reads):
+        self.value, self.clock, self.reads = value, clock, reads
+
+    def __getitem__(self, i):
+        return _Probe(self.value[i], self.clock, self.reads)
+
+    def __array__(self, dtype=None, copy=None):
+        self.reads.append(self.clock())
+        return np.asarray(self.value, dtype=dtype)
+
+
+def test_wait_spans_hold_the_host_conversion(tiny_setup):
+    cfg, params = tiny_setup
+    reqs = [_req(i, 8 + 3 * i, 6, vocab=cfg.vocab_size, seed=3)
+            for i in range(3)]
+    rec = FlightRecorder(1 << 16)
+    clock = TickClock()
+    engine = _engine(cfg, params, reqs, tracer=Tracer(rec, clock=clock))
+    reads = {"serve.decode.wait": [], "serve.prefill.wait": []}
+    serve0, prefill0 = engine._serve, engine._prefill
+
+    def serve(*args):
+        nxt, logits, cache = serve0(*args)
+        return _Probe(nxt, clock, reads["serve.decode.wait"]), logits, cache
+
+    def prefill(seq):
+        fn = prefill0(seq)
+
+        def run(*args):
+            logits, row1 = fn(*args)
+            return _Probe(logits, clock, reads["serve.prefill.wait"]), row1
+        return run
+
+    engine._serve, engine._prefill = serve, prefill
+    for r in reqs:
+        engine.submit(r)
+    engine.run(max_steps=200)
+    assert len(engine.completed) == len(reqs)
+    spans = [e for e in rec.snapshot() if e["type"] == "span"]
+    by_id = {e["span_id"]: e for e in spans}
+    for name, times in reads.items():
+        waits = [e for e in spans if e["name"] == name]
+        assert times and len(times) == len(waits), name
+        for t, w in zip(sorted(times), sorted(waits, key=lambda e: e["t0"])):
+            assert w["t0"] < t < w["t1"], name
+            # the decode and prefill spans close after their host sync
+            assert by_id[w["parent_id"]]["t1"] > t
+
+
+def test_slot_programs_have_stable_names(tiny_setup):
+    cfg, params = tiny_setup
+    reqs = [_req(0, 8, 4, vocab=cfg.vocab_size)]
+    e = _engine(cfg, params, reqs)
+    row = slot_get(e.cache, e.axes, 0)
+    row1 = jax.tree.map(lambda l, a: jax.numpy.expand_dims(l, a), row,
+                        e.axes)
+    for fn, args, name in ((e._get, (e.cache, 0), "slot_read"),
+                           (e._set, (e.cache, 0, row), "slot_write"),
+                           (e._insert, (e.cache, 0, row1), "cache_insert")):
+        assert f"module @jit_{name} " in fn.lower(*args).as_text(), name
+
+
+def test_annotate_sink_leaves_tokens_and_counters_identical(tiny_setup):
+    plain, _, _ = _traced_failure_run(tiny_setup)
+    annotated, _, events = _traced_failure_run(
+        tiny_setup, annotate=jax.profiler.TraceAnnotation)
+    assert events and plain.completed == annotated.completed
+    assert plain.step_no == annotated.step_no
+    assert (plain.metrics.registry.to_json()
+            == annotated.metrics.registry.to_json())
+    assert plain.metrics.snapshots > 0 and plain.metrics.restores > 0
