@@ -310,7 +310,9 @@ def main(argv=None) -> None:
     print(f"smoke serve: {cfg.name} {args.chips} chip(s), "
           f"{int(s['completed'])}/{len(run.requests)} completed, "
           f"{int(s['failures'])} failures, {int(s['restores'])} snapshot "
-          f"restores; wall {run.wall_s:.3f} s (compiles and logit copies "
+          f"restores, {int(s['snapshots'])} snapshots of which "
+          f"{int(run.engine.metrics.snapshot_deltas)} extended a lineage; "
+          f"wall {run.wall_s:.3f} s (compiles and logit copies "
           f"included), {run.engine.metrics.decode_tokens} decode tokens = "
           f"{run.engine.metrics.decode_tokens / run.wall_s:.1f} tok/s")
     print(f"smoke parity: {par['rows']} engine logit rows vs the static "

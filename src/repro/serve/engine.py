@@ -16,7 +16,9 @@ The serving counterpart of the CheckpointHEFT runtime (paper Algorithm 3):
   else re-prefilling from scratch (steps 16-21);
 * snapshots are taken every ``lambda`` generated tokens per slot, with
   ``lambda`` re-derived online by :class:`repro.ft.interval.DynamicInterval`
-  from observed failures (Lemma 3.1).
+  from observed failures (Lemma 3.1).  Each copies off the device, and
+  hashes, only the append-only cache rows the slot wrote since its previous
+  snapshot, plus any state copied whole (:mod:`repro.serve.snapshot`).
 
 Supported model families: **all of them**.  Dense / MoE causal-KV
 architectures prefill into right-padded buckets (causality + the
@@ -82,7 +84,7 @@ from repro.obs.trace import NULL_TRACER
 from .metrics import ServeMetrics
 from .queue import AdmissionQueue, Request, WorkItem, prompt_bucket
 from .replicas import ReplicaPolicy, WorkerPool, uniform_policy
-from .snapshot import (DecodeSnapshot, SnapshotStore, cache_batch_axes,
+from .snapshot import (DecodeSnapshot, Lineage, SlotLayout, SnapshotStore,
                        slot_get, slot_set)
 
 __all__ = ["EngineConfig", "ServeEngine", "engine_supported",
@@ -225,7 +227,9 @@ class ServeEngine:
 
         cache_len = self.ecfg.cache_len
         self.cache = init_slot_cache(cfg, pool.n_slots, cache_len)
-        self.axes = cache_batch_axes(cfg, cache_len)
+        self.layout = SlotLayout(
+            cfg, cache_len, jax.tree.map(lambda l: l.sharding, self.cache))
+        self.axes = self.layout.batch_axes
         self._serve = jax.jit(make_serve_step(cfg, cache_axes=self.axes),
                               donate_argnums=(1,))
         axes = self.axes
@@ -245,8 +249,39 @@ class ServeEngine:
         self._get = jax.jit(slot_read)
         self._set = jax.jit(slot_write, donate_argnums=(0,))
         self._insert = jax.jit(cache_insert, donate_argnums=(0,))
+        self._compile_snapshot_programs()
+        self._lineage = [Lineage(self.layout.chunk) for _ in self.slots]
         self._prefill_fns: dict[int, callable] = {}
         self.logit_log: list[tuple[int, int, np.ndarray]] = []
+
+    def _compile_snapshot_programs(self) -> None:
+        """Compile the snapshot's chunk and state programs now, against the
+        cache's own shapes and shardings: one shape each serves every
+        position, so taking or restoring a snapshot never compiles."""
+        lay = self.layout
+        cache = jax.tree.map(
+            lambda l: jax.ShapeDtypeStruct(l.shape, l.dtype,
+                                           sharding=l.sharding), self.cache)
+        i32 = jax.ShapeDtypeStruct((), jnp.int32)
+
+        def compile_(fn, *args, donate=()):
+            return jax.jit(fn, donate_argnums=donate).lower(*args).compile()
+
+        def host(shapes):
+            return [jax.ShapeDtypeStruct(x.shape, x.dtype) for x in shapes]
+
+        self._read_rows = self._write_rows = None
+        self._read_state = self._write_state = None
+        if lay.rows_leaves:
+            rows = host(jax.eval_shape(lay.slot_read_rows, cache, i32, i32))
+            self._read_rows = compile_(lay.slot_read_rows, cache, i32, i32)
+            self._write_rows = compile_(lay.slot_write_rows, cache, i32, i32,
+                                        rows, donate=(0,))
+        if lay.state_leaves:
+            state = host(jax.eval_shape(lay.slot_read_state, cache, i32))
+            self._read_state = compile_(lay.slot_read_state, cache, i32)
+            self._write_state = compile_(lay.slot_write_state, cache, i32,
+                                         state, donate=(0,))
 
     # -- submission ----------------------------------------------------------
     def submit(self, req: Request) -> int:
@@ -329,6 +364,7 @@ class ServeEngine:
         slot.since_snapshot = 0
         slot.req = None
         slot.tokens = []
+        self._lineage[slot.sid] = Lineage(self.layout.chunk)
 
     def _kill_copy(self, slot: _Slot, *, resubmit_if_last: bool) -> None:
         rid = slot.rid
@@ -466,8 +502,7 @@ class ServeEngine:
                     intact = self.store.verify(snap)
                 if intact:
                     with tr.span("serve.restore.write"):
-                        row = jax.tree.map(jnp.asarray, snap.cache_row)
-                        self.cache = self._set(self.cache, slot.sid, row)
+                        self._restore(slot.sid, snap)
             if not intact:
                 # checksum mismatch: quarantine the snapshot and fall back to
                 # a full re-prefill — never resume from garbage decode state
@@ -503,6 +538,25 @@ class ServeEngine:
             self.metrics.prefill_tokens += seq + offset
         if len(slot.tokens) >= slot.max_new:
             self._finish(slot, t)
+
+    def _restore(self, sid: int, snap: DecodeSnapshot) -> None:
+        """Write a verified snapshot into slot ``sid``: rows ``[0, pos)``
+        chunk by chunk (the last chunk's rows at and above ``pos`` zero;
+        rows past it keep the slot's earlier finite values, which decode
+        attention never weights), the other leaves whole.  The slot's
+        lineage continues from the snapshot's sealed chunks."""
+        lay = self.layout
+        at = np.int32(sid)
+        for k, rows in enumerate(snap.chunks):
+            if rows and len(rows[0]) < lay.chunk:   # the partial last chunk
+                rows = [np.concatenate([r, np.zeros(
+                    (lay.chunk - len(r), *r.shape[1:]), r.dtype)])
+                        for r in rows]
+            self.cache = self._write_rows(self.cache, at,
+                                          np.int32(k * lay.chunk), rows)
+        if lay.state_leaves:
+            self.cache = self._write_state(self.cache, at, snap.state)
+        self._lineage[sid] = Lineage.resume(lay.chunk, snap)
 
     # -- one batched decode step ---------------------------------------------
     def _decode(self, t: int) -> None:
@@ -576,20 +630,33 @@ class ServeEngine:
             return
         cadence = self._snapshot_every()
         tr = self.tracer
+        lay = self.layout
         for s in self.slots:
             if not (s.busy and s.since_snapshot >= cadence):
                 continue
+            lin = self._lineage[s.sid]
+            delta = bool(lin.chunks)
             with tr.span("serve.snapshot.take", rid=s.rid, step=t):
                 with tr.span("serve.snapshot.copy"):
-                    row = jax.device_get(self._get(self.cache, s.sid))
-                snap = DecodeSnapshot(
-                    rid=s.rid, pos=s.pos, tokens=list(s.tokens),
-                    last_token=s.last_token, cache_row=row, step=t)
+                    # only the chunks written since the lineage's last
+                    # sealed one: all dispatched, then one copy to the host
+                    sid = np.int32(s.sid)
+                    rows = [self._read_rows(self.cache, sid,
+                                            np.int32(k * lay.chunk))
+                            for k in lin.pending(s.pos)]
+                    state = (self._read_state(self.cache, sid)
+                             if lay.state_leaves else [])
+                    rows, state = jax.device_get((rows, state))
                 with tr.span("serve.snapshot.digest"):
+                    snap = lin.extend(rows, state, rid=s.rid, pos=s.pos,
+                                      tokens=s.tokens,
+                                      last_token=s.last_token, step=t)
                     self.store.save(snap)
                 self.metrics.snapshots += 1
+                self.metrics.snapshot_deltas += delta
                 self.metrics.snapshot_bytes += sum(
-                    leaf.nbytes for leaf in jax.tree.leaves(row))
+                    leaf.nbytes for arrays in (*rows, state)
+                    for leaf in arrays)
                 self.metrics.snapshot_overhead_tokens += \
                     self.ecfg.snapshot_gamma
                 tr.event("serve.snapshot", rid=s.rid, pos=s.pos, step=t)

@@ -79,6 +79,9 @@ class ServeMetrics:
         "resubmissions": ("serve_events_total", {"kind": "resubmission"}),
         "restores": ("serve_events_total", {"kind": "snapshot_restore"}),
         "snapshots": ("serve_events_total", {"kind": "snapshot"}),
+        # snapshots that extended a slot's lineage instead of copying the
+        # cache rows from row 0
+        "snapshot_deltas": ("serve_events_total", {"kind": "snapshot_delta"}),
         "capacity_events": ("serve_events_total",
                             {"kind": "capacity_loss"}),
         "slowdown_events": ("serve_events_total", {"kind": "slowdown"}),

@@ -11,6 +11,7 @@ Recovery-path coverage map (one test per taxonomy entry):
 * ``ckpt_corrupt``     -> test_restore_falls_back_to_previous_checkpoint /
                           test_train_ckpt_corrupt_falls_back
 * ``snapshot_corrupt`` -> test_serve_snapshot_corrupt_falls_back_to_reprefill
+                          / test_serve_snapshot_corrupt_survives_delta_snapshots
 * ``nan_poison``       -> test_train_nan_poison_guard_skips_batch
 * ``net_partition``    -> test_train_net_partition_parks_single_actor
                           (quorum/minority split: tests/test_crosspod.py)
@@ -35,8 +36,10 @@ from repro.ft import (CheckpointStore, DynamicInterval, FaultInjector,
                       TrainingCoordinator)
 from repro.models import lm
 from repro.optim import adamw_init
+from repro.obs import FlightRecorder, Tracer
 from repro.serve import (AdmissionQueue, EngineConfig, Request, ServeEngine,
                          WorkItem, WorkerPool, prompt_bucket, uniform_policy)
+from repro.serve import snapshot
 
 
 # ------------------------------------------------------------- fixtures ----
@@ -67,7 +70,8 @@ def _req(rid, plen, newt, *, arrival=0, deadline=None, vocab=256, seed=0):
 
 
 def _engine(cfg, params, reqs, *, workers=2, slots=2, chaos=None,
-            policy=None, snapshot_lambda=4, max_steps=2_000):
+            policy=None, snapshot_lambda=4, max_steps=2_000, tracer=None,
+            before_run=None):
     cache_len = max(prompt_bucket(r.prompt_len) + r.max_new_tokens
                     for r in reqs)
     pool = WorkerPool(workers, slots, mtbf_steps=0.0, mttr_steps=6, seed=0)
@@ -75,7 +79,9 @@ def _engine(cfg, params, reqs, *, workers=2, slots=2, chaos=None,
         cfg, EngineConfig(cache_len=cache_len, q_chunk=32,
                           snapshot_lambda=snapshot_lambda),
         pool=pool, policy=policy or uniform_policy(1), params=params,
-        chaos=chaos)
+        chaos=chaos, tracer=tracer)
+    if before_run is not None:
+        before_run(engine)
     for r in reqs:
         engine.submit(r)
     engine.run(max_steps=max_steps)
@@ -394,6 +400,78 @@ def test_serve_snapshot_corrupt_falls_back_to_reprefill(serve_setup):
     assert m.restores == 0                    # corrupt snapshot never used
     assert m.resubmissions == 1
     assert faulty.completed[0] == clean.completed[0]
+
+
+def test_serve_snapshot_corrupt_survives_delta_snapshots(serve_setup,
+                                                         monkeypatch):
+    """A flip in a stored chunk is never laundered: a later delta snapshot
+    of the same lineage shares the flipped chunk but takes its checksum
+    from the bytes as they came off the device, so it fails its verify at
+    the resume and the request re-prefills to the clean tokens."""
+    monkeypatch.setattr(snapshot, "CHUNK_ROWS", 8)
+    cfg, params = serve_setup
+    # prompt 16 (pos 16 after prefill), 24 new: cache_len 40, 8-row chunks;
+    # at a cadence of 8 the snapshots fall at pos 24 (end of step 7: three
+    # sealed chunks and nothing else, so any flip lands in one) and pos 32
+    # (end of step 15: one more chunk, a delta)
+    reqs = [_req(0, 16, 24, vocab=cfg.vocab_size, seed=5)]
+    clean = _engine(cfg, params, reqs, workers=1, slots=1,
+                    snapshot_lambda=8)
+    trace = FaultTrace(events=[
+        FaultEvent(step=10, kind=SNAPSHOT_CORRUPT, seed=123),
+        FaultEvent(step=18, kind=HOST_CRASH, targets=(0,), duration=2)])
+    rec = FlightRecorder(1 << 12)
+    faulty = _engine(cfg, params, reqs, workers=1, slots=1,
+                     snapshot_lambda=8, chaos=ChaosEngine(trace),
+                     tracer=Tracer(rec))
+    taken = [(e["attrs"]["step"], e["attrs"]["pos"]) for e in rec.snapshot()
+             if e["name"] == "serve.snapshot"]
+    assert taken[:2] == [(7, 24), (15, 32)]
+    m = faulty.metrics
+    assert m.snapshots_corrupted == 1
+    assert m.snapshot_deltas >= 1
+    assert m.snapshot_restore_failures == 1   # the delta carried the flip
+    assert m.restores == 0
+    assert m.resubmissions == 1
+    assert faulty.completed[0] == clean.completed[0]
+
+
+def test_serve_restore_ignores_finite_rows_above_pos(serve_setup,
+                                                     monkeypatch):
+    """A restore writes rows [0, pos) only (its last chunk zero-padded);
+    the target slot's rows above hold whatever it held.  Filled with finite
+    garbage first, they change no token: decode attention gives every row
+    above the written position exactly zero weight."""
+    monkeypatch.setattr(snapshot, "CHUNK_ROWS", 8)
+    cfg, params = serve_setup
+    reqs = [_req(i, 8 + 3 * i, 16, vocab=cfg.vocab_size, seed=3)
+            for i in range(4)]
+    clean = _engine(cfg, params, reqs, snapshot_lambda=3)
+    trace = FaultTrace(events=[
+        FaultEvent(step=9, kind=HOST_CRASH, targets=(0,), duration=2)])
+    rng = np.random.default_rng(0)
+    poisoned = []
+
+    def poison_restores(engine):
+        restore = engine._restore
+
+        def garbage_then_restore(sid, snap):
+            row = jax.device_get(engine._get(engine.cache, sid))
+            junk = jax.tree.map(
+                lambda l: (rng.normal(size=l.shape) * 4).astype(l.dtype), row)
+            engine.cache = engine._set(engine.cache, sid, junk)
+            poisoned.append((sid, snap.pos))
+            restore(sid, snap)
+        engine._restore = garbage_then_restore
+
+    faulty = _engine(cfg, params, reqs, snapshot_lambda=3,
+                     chaos=ChaosEngine(trace), before_run=poison_restores)
+    assert faulty.metrics.restores >= 1 and poisoned
+    # rows past the last restored chunk kept the garbage
+    assert any(-(-pos // 8) * 8 < faulty.ecfg.cache_len
+               for _, pos in poisoned)
+    for rid in clean.completed:
+        assert clean.completed[rid] == faulty.completed[rid], rid
 
 
 def test_serve_chaos_trace_replay_is_identical(serve_setup):

@@ -1,6 +1,11 @@
 """Tests for repro.serve: queue, snapshots, CRCH routing, and the engine's
 failure-determinism guarantee."""
 import collections
+import os
+import subprocess
+import sys
+import textwrap
+from pathlib import Path
 
 import jax
 import numpy as np
@@ -14,7 +19,9 @@ from repro.serve import (AdmissionQueue, EngineConfig, Request, ServeEngine,
                          engine_supported, greedy_reference, prompt_bucket,
                          reference_logits, request_class, request_features,
                          uniform_policy)
-from repro.serve.snapshot import cache_batch_axes, slot_get, slot_set
+from repro.serve import snapshot
+from repro.serve.snapshot import (SlotLayout, cache_batch_axes, slot_get,
+                                  slot_set)
 
 
 def _req(rid, plen, newt, *, arrival=0, deadline=None, vocab=256, seed=0,
@@ -119,6 +126,40 @@ def test_slot_get_set_roundtrip(arch):
         assert (got[0] == 0).all() and (got[2] == 0).all()  # untouched
 
 
+# the snapshot tests' families: dense KV, RWKV state, RG-LRU hybrid, enc-dec
+SNAPSHOT_ARCHS = ("olmo-1b", "rwkv6-3b", "recurrentgemma-2b", "whisper-small")
+
+
+def _leaf_names(cfg, cache_len):
+    cache = jax.eval_shape(lambda: lm.init_cache(cfg, 2, cache_len))
+    return [jax.tree_util.keystr(p, simple=True, separator="/")
+            for p, _ in jax.tree_util.tree_flatten_with_path(cache)[0]]
+
+
+@pytest.mark.parametrize("arch,cache_len,append_only", [
+    ("olmo-1b", 48, {"k", "v"}),
+    ("rwkv6-3b", 48, set()),
+    # the local-attention ring stops growing at the window (16 tiny)...
+    ("recurrentgemma-2b", 48, set()),
+    # ...below it, it is indexed by position and append-only
+    ("recurrentgemma-2b", 12, {"k", "v"}),
+    ("whisper-small", 48, {"k", "v"}),
+])
+def test_snapshot_leaf_classification(arch, cache_len, append_only):
+    """Leaves that grow with the cache length are append-only along that
+    axis and move in chunks; every other leaf is copied whole."""
+    cfg = get_config(arch, tiny=True)
+    lay = SlotLayout(cfg, cache_len)
+    names = _leaf_names(cfg, cache_len)
+    assert {names[i] for i, _, _ in lay.rows_leaves} == append_only
+    assert {names[i] for i, _ in lay.state_leaves} == \
+        set(names) - append_only
+    for i, b, s in lay.rows_leaves:
+        assert (b, s) == (1, 2)   # (layers, batch, seq, kv heads, head dim)
+    assert lay.chunk == (min(cache_len, snapshot.CHUNK_ROWS)
+                         if append_only else 0)
+
+
 # --------------------------------------------------------------- metrics ----
 
 def test_metrics_wastage_accounting():
@@ -179,20 +220,28 @@ def _run_engine(cfg, params, reqs, **kw):
     return engine
 
 
-def test_engine_failure_resume_matches_failure_free(tiny_setup):
-    """Mid-decode worker failure + snapshot resume must reproduce the
-    failure-free greedy tokens exactly (Algorithm 3's correctness bar)."""
-    cfg, params = tiny_setup
-    reqs = [_req(i, 8 + 3 * i, 16, vocab=cfg.vocab_size, seed=3)
-            for i in range(4)]
-    clean = _run_engine(cfg, params, reqs)
-    faulty = _run_engine(cfg, params, reqs, fail=(9, 0))
+def _resume_matches_failure_free(cfg, params, reqs, **kw):
+    """Run ``reqs`` failure-free and with worker 0 failing at step 9: every
+    request completes and delivers the failure-free tokens exactly
+    (Algorithm 3's correctness bar).  Returns the faulty engine."""
+    clean = _run_engine(cfg, params, reqs, **kw)
+    faulty = _run_engine(cfg, params, reqs, fail=(9, 0), **kw)
     assert len(clean.completed) == len(reqs)
     assert len(faulty.completed) == len(reqs)
     assert faulty.metrics.failures >= 1
     assert faulty.metrics.resubmissions >= 1
     for rid in clean.completed:
         assert clean.completed[rid] == faulty.completed[rid], rid
+    return faulty
+
+
+def test_engine_failure_resume_matches_failure_free(tiny_setup):
+    """Mid-decode worker failure + snapshot resume must reproduce the
+    failure-free greedy tokens exactly."""
+    cfg, params = tiny_setup
+    _resume_matches_failure_free(cfg, params, [
+        _req(i, 8 + 3 * i, 16, vocab=cfg.vocab_size, seed=3)
+        for i in range(4)])
 
 
 def test_engine_replicated_requests_survive_single_worker_loss(tiny_setup):
@@ -325,14 +374,144 @@ def test_engine_rwkv_failure_resume_matches_failure_free():
     overwrite argument — the snapshot itself must be exact)."""
     cfg = get_config("rwkv6-3b", tiny=True)
     params = lm.init_params(jax.random.key(1), cfg)
-    reqs = [_req(i, 7 + 3 * i, 16, seed=17, cfg=cfg) for i in range(4)]
-    clean = _run_engine(cfg, params, reqs)
-    faulty = _run_engine(cfg, params, reqs, fail=(9, 0))
-    assert len(faulty.completed) == len(reqs)
-    assert faulty.metrics.failures >= 1
-    assert faulty.metrics.resubmissions >= 1
-    for rid in clean.completed:
-        assert clean.completed[rid] == faulty.completed[rid], rid
+    _resume_matches_failure_free(cfg, params, [
+        _req(i, 7 + 3 * i, 16, seed=17, cfg=cfg) for i in range(4)])
+
+
+@pytest.mark.parametrize("arch", SNAPSHOT_ARCHS)
+def test_delta_snapshot_resume_matches_failure_free(arch, monkeypatch):
+    """Worker failures with a short snapshot cadence and 8-row chunks: the
+    requests resume from snapshots that extended their lineage, and deliver
+    the failure-free tokens exactly."""
+    monkeypatch.setattr(snapshot, "CHUNK_ROWS", 8)
+    cfg = get_config(arch, tiny=True)
+    params = lm.init_params(jax.random.key(2), cfg)
+    faulty = _resume_matches_failure_free(cfg, params, [
+        _req(i, 8 + 3 * i, 16, seed=19, cfg=cfg) for i in range(4)],
+        snapshot_lambda=2)
+    m = faulty.metrics
+    assert m.restores >= 1 and m.snapshot_restore_failures == 0
+    if faulty.layout.rows_leaves:
+        assert m.snapshot_deltas >= 1
+
+
+def test_delta_snapshot_resume_on_seq_sharded_cache():
+    """On four devices the KV cache is split along its sequence axis, and a
+    chunk (6 rows, a shard holding 12) is read and written by the shard
+    that holds it: resuming from delta snapshots still delivers the
+    failure-free tokens.  In a child process of its own, since the device
+    count is fixed when JAX starts."""
+    code = textwrap.dedent("""
+        import jax, numpy as np
+        from repro.configs import get_config
+        from repro.distributed.sharding import use_rules
+        from repro.launch.mesh import make_mesh
+        from repro.models import lm
+        from repro.serve import (EngineConfig, Request, ServeEngine,
+                                 WorkerPool, snapshot, uniform_policy)
+        snapshot.CHUNK_ROWS = 8
+        cfg = get_config("olmo-1b", tiny=True)
+        params = lm.init_params(jax.random.key(2), cfg)
+        rng = np.random.default_rng(19)
+        reqs = [Request(rid=i, prompt=rng.integers(
+                    1, cfg.vocab_size, 8 + 3 * i).astype(np.int32),
+                    max_new_tokens=16, arrival=0, deadline=None)
+                for i in range(4)]
+
+        def run(fail):
+            pool = WorkerPool(2, 2, mtbf_steps=0.0, mttr_steps=6, seed=0)
+            if fail:
+                pool.force_failure(9, wid=0)
+            with use_rules(make_mesh(4)):
+                e = ServeEngine(cfg, EngineConfig(
+                    cache_len=48, q_chunk=32, snapshot_lambda=2),
+                    pool=pool, policy=uniform_policy(1), params=params)
+                for r in reqs:
+                    e.submit(r)
+                e.run(max_steps=2000)
+            return e
+
+        clean, faulty = run(False), run(True)
+        m = faulty.metrics
+        assert faulty.cache["k"].sharding.spec[2] == "model"
+        assert faulty.layout.chunk == 6
+        assert m.restores >= 1 and m.snapshot_deltas >= 1
+        assert m.snapshot_restore_failures == 0
+        assert len(faulty.completed) == len(reqs)
+        assert clean.completed == faulty.completed
+
+        # every chunk of one slot reads as the cache holds it, and a write
+        # lands in that slot's chunk alone
+        C, (i, b, s) = faulty.layout.chunk, faulty.layout.rows_leaves[0]
+        assert (b, s) == (1, 2)
+        before = np.array(jax.tree.leaves(faulty.cache)[i])
+        cache = faulty.cache
+        for start in range(0, 48, C):
+            got = faulty._read_rows(cache, np.int32(3), np.int32(start))[0]
+            want = np.moveaxis(before[:, 3, start:start + C], 1, 0)
+            assert (np.asarray(got) == want).all(), start
+            new = rng.normal(size=want.shape).astype(want.dtype)
+            cache = faulty._write_rows(
+                cache, np.int32(2), np.int32(start),
+                [new] + [np.zeros_like(r) for r in jax.device_get(
+                    faulty._read_rows(cache, np.int32(2),
+                                      np.int32(start)))[1:]])
+            after = jax.device_get(jax.tree.leaves(cache)[i])
+            assert (np.moveaxis(after[:, 2, start:start + C], 1, 0)
+                    == new).all(), start
+            before[:, 2, start:start + C] = after[:, 2, start:start + C]
+            assert (after == before).all(), start
+        print("ok")
+        """)
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4")
+    out = subprocess.run([sys.executable, "-c", code],
+                         cwd=Path(__file__).resolve().parents[1], env=env,
+                         capture_output=True, text=True, timeout=600)
+    assert out.returncode == 0, out.stderr[-4000:]
+    assert out.stdout.strip().endswith("ok")
+
+
+@pytest.mark.parametrize("arch", SNAPSHOT_ARCHS)
+def test_snapshot_copies_rows_written_since_last(arch, monkeypatch):
+    """A lineage's first snapshot copies the chunks holding rows [0, pos);
+    each later one only those from its first unsealed chunk (at most 2 at
+    a cadence of no more than a chunk), plus the whole-copied leaves every
+    time.  ``snapshot_deltas`` counts the later ones."""
+    monkeypatch.setattr(snapshot, "CHUNK_ROWS", 8)
+    cfg = get_config(arch, tiny=True)
+    params = lm.init_params(jax.random.key(2), cfg)
+    reqs = [_req(0, 11, 32, seed=23, cfg=cfg)]   # cache_len 48
+    e = _engine(cfg, params, reqs, snapshot_lambda=5)
+    lay = e.layout
+    i32 = jax.ShapeDtypeStruct((), np.int32)
+    shapes = (jax.eval_shape(lay.slot_read_rows, e.cache, i32, i32)
+              + jax.eval_shape(lay.slot_read_state, e.cache, i32))
+    chunk_b = sum(x.size * x.dtype.itemsize for x in shapes[:len(
+        lay.rows_leaves)])
+    state_b = sum(x.size * x.dtype.itemsize for x in shapes[len(
+        lay.rows_leaves):])
+    e.submit(reqs[0])
+    copied = []   # (pos, bytes) per snapshot
+    while e.pending():
+        n, b = e.metrics.snapshots, e.metrics.snapshot_bytes
+        e.step()
+        if e.metrics.snapshots > n:
+            slot = next(s for s in e.slots if s.rid == 0)
+            copied.append((slot.pos, e.metrics.snapshot_bytes - b))
+    assert len(copied) >= 4
+    C = lay.chunk
+    prev = 0
+    for k, (pos, nbytes) in enumerate(copied):
+        chunks = -(-pos // C) - prev // C if C else 0
+        assert nbytes == chunks * chunk_b + state_b, (k, pos)
+        if k and C:
+            assert 1 <= chunks <= 2
+        prev = pos
+    deltas = len(copied) - 1 if C and copied[0][0] >= C else 0
+    assert e.metrics.snapshot_deltas == deltas
+    assert e.metrics.registry.value(
+        "serve_events_total", kind="snapshot_delta") == deltas
 
 
 # -------------------------------------------------------------- tracing ----
@@ -401,7 +580,9 @@ def test_engine_tick_spans_nest_under_their_parents(tiny_setup):
     resumed = [a for a in starts if a["resumed"]]
     assert resumed and not any(a["first"] for a in resumed)
     assert len(resumed) == engine.metrics.restores
-    # snapshot bytes: one slot row per snapshot
+    # snapshot bytes: at this cache length one chunk spans the whole
+    # sequence, so each snapshot copies one slot row
+    assert engine.layout.chunk == engine.ecfg.cache_len
     row = jax.device_get(engine._get(engine.cache, 0))
     per = sum(leaf.nbytes for leaf in jax.tree.leaves(row))
     assert engine.metrics.snapshot_bytes == per * engine.metrics.snapshots
@@ -472,6 +653,10 @@ def test_slot_programs_have_stable_names(tiny_setup):
                            (e._set, (e.cache, 0, row), "slot_write"),
                            (e._insert, (e.cache, 0, row1), "cache_insert")):
         assert f"module @jit_{name} " in fn.lower(*args).as_text(), name
+    # the snapshot's chunk programs, compiled when the engine is built
+    for fn, name in ((e._read_rows, "slot_read_rows"),
+                     (e._write_rows, "slot_write_rows")):
+        assert f"HloModule jit_{name}," in fn.as_text(), name
 
 
 def test_annotate_sink_leaves_tokens_and_counters_identical(tiny_setup):

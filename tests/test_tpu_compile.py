@@ -2,25 +2,30 @@
 
 The TPU compiler refuses what interpret mode and the CPU backend accept: a
 program that does not fit the chip's memory, a kernel slice not aligned to
-the tiling.  These compiles guard the serve step, prefill and the Pallas
-distance kernel at the sizes ``chip_smoke.py`` runs, at no chip time.
+the tiling.  These compiles guard the serve step, prefill, the Pallas
+distance kernel and the snapshot's chunk programs on a KV cache sharded
+over four chips, at the sizes ``chip_smoke.py`` runs, at no chip time.
 
 The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and every test worker imports
 this file.
 """
+import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import AxisType, Mesh, NamedSharding, SingleDeviceSharding
 
 from repro.configs import get_config
+from repro.distributed import params as pshard
 from repro.distributed.steps import make_prefill_step, make_serve_step
 from repro.kernels.pairwise_affinity import ops as pa_ops
 from repro.models import lm
-from repro.serve.snapshot import cache_batch_axes
+from repro.serve.snapshot import SlotLayout, cache_batch_axes
 
 V5E_HBM_BYTES = 16 * 2 ** 30
 
@@ -109,3 +114,40 @@ def test_pairwise_distance_kernel_compiles_for_the_chip(one_chip,
     pts = jax.ShapeDtypeStruct((700, 10), jnp.float32, sharding=one_chip)
     compiled = jax.jit(pa_ops.pairwise_distance).lower(pts).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_snapshot_chunk_programs_stay_inside_kv_seq_shards(topo,
+                                                           no_compile_cache):
+    """On a ``(1, 4)`` mesh the KV cache is split along its sequence axis
+    (``kv_seq``).  Reading or writing one chunk of one slot moves that
+    chunk only: no collective larger than a chunk from each chip, and no
+    scratch buffer of the cache's size."""
+    cfg = get_config("olmo-1b")
+    slots, cache_len = 8, 576       # chip_smoke.py --chips 4
+    mesh = Mesh(np.array(topo.devices[:4]).reshape(1, 4), ("data", "model"),
+                axis_types=(AxisType.Auto,) * 2)
+    shapes = jax.eval_shape(lambda: lm.init_cache(cfg, slots, cache_len))
+    cache = jax.tree.map(
+        lambda a, p: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                          sharding=NamedSharding(mesh, p)),
+        shapes, pshard.cache_specs(shapes, cfg, mesh))
+    assert cache["k"].sharding.spec[2] == "model"
+    lay = SlotLayout(cfg, cache_len,
+                     jax.tree.map(lambda a: a.sharding, cache))
+    assert (cache_len // 4) % lay.chunk == 0
+    i32 = jax.ShapeDtypeStruct((), jnp.int32)
+    rows = jax.eval_shape(lay.slot_read_rows, cache, i32, i32)
+    chunk_elems = max(math.prod(r.shape) for r in rows)
+    shard_bytes = cache["k"].size * cache["k"].dtype.itemsize // 4
+    read = jax.jit(lay.slot_read_rows).lower(cache, i32, i32).compile()
+    write = jax.jit(lay.slot_write_rows, donate_argnums=(0,)).lower(
+        cache, i32, i32, [jax.ShapeDtypeStruct(r.shape, r.dtype)
+                          for r in rows]).compile()
+    for compiled in (read, write):
+        for dims in re.findall(
+                r"= \w+\[([\d,]*)\][^ ]* (?:all-gather|all-reduce|"
+                r"collective-permute|all-to-all)(?:-start)?\(",
+                compiled.as_text()):
+            n = math.prod(int(d) for d in dims.split(",") if d)
+            assert n <= 4 * chunk_elems, dims
+        assert compiled.memory_analysis().temp_size_in_bytes < shard_bytes
