@@ -5,9 +5,10 @@ the program is mended in one place: the served path as the launcher
 builds it (a ``(1, chips)`` mesh under ``use_rules``, weights placed by
 the program's parameter shardings, ``ServeEngine`` with a ``WorkerPool``
 and a ``crch_policy`` fitted on the run's own requests), the programs it
-jits, its counters (``ServeMetrics``) and its spans and events (a
-``repro.obs`` tracer whose records the harness reads each tick), and the
-slot state from which the client's view of each request is taken.
+jits, its counters (``ServeMetrics`` and every series of its registry)
+and its spans and events (a ``repro.obs`` tracer whose records the
+harness reads each tick), and the slot state from which the client's
+view of each request is taken.
 
 The weights are the benchmark's, made by the configuration's reference
 ``init`` from the seed's key in one jitted call straight into the
@@ -77,6 +78,25 @@ def _request(spec, arrival: int) -> Request:
     return Request(rid=spec.rid, prompt=spec.prompt,
                    max_new_tokens=spec.max_new, arrival=arrival,
                    deadline=None)
+
+
+def registry_series(registry) -> dict[str, float]:
+    """Every series of a ``repro.obs`` metrics registry under one stable
+    name: ``family{label=value,...}`` with the labels in the family's
+    order (``serve_bytes_total{kind=snapshot}``), or ``family`` where it
+    has no labels.  A histogram gives ``family_count{...}`` and
+    ``family_sum{...}``.  A series appears once the program first moves
+    it; a reader takes a missing one as 0."""
+    out = {}
+    for family, inst in registry.to_json().items():
+        for key, value in inst["series"].items():
+            labels = "{" + key.replace("|", ",") + "}" if key else ""
+            if isinstance(value, dict):
+                out[f"{family}_count{labels}"] = float(value["count"])
+                out[f"{family}_sum{labels}"] = float(value["sum"])
+            else:
+                out[family + labels] = float(value)
+    return out
 
 
 class System:
@@ -161,6 +181,9 @@ class System:
         return out
 
     def counters(self) -> dict[str, float]:
+        """The ten counters the harness has always read, and every series
+        of the engine's metrics registry (``registry_series``), so that a
+        reader finds a counter the program adds without a change here."""
         m = self.engine.metrics
         return {"prefill_tokens": float(m.prefill_tokens),
                 "decode_tokens": float(m.decode_tokens),
@@ -171,7 +194,8 @@ class System:
                 "restores": float(m.restores),
                 "snapshots": float(m.snapshots),
                 "shed": float(m.shed),
-                "rejected": float(m.rejected_on_arrival)}
+                "rejected": float(m.rejected_on_arrival),
+                **registry_series(m.registry)}
 
     def outputs(self) -> dict[int, list[int]]:
         return {rid: list(t) for rid, t in self.engine.completed.items()}

@@ -50,28 +50,51 @@ class _Compiles:
 
 
 class _Profiler:
-    """Starts and stops the profiler at tick boundaries."""
+    """Starts and stops the profiler at tick boundaries, and reads the
+    program's counters (``counters()``) just before it starts and just
+    after it stops; with ``every_tick``, also at each tick boundary in
+    between, for counts that read a tick's counters."""
 
-    def __init__(self, t0: float, seconds: float, log_dir: str):
+    def __init__(self, t0: float, seconds: float, log_dir: str, counters,
+                 every_tick: bool = False):
         self.start_at = t0 + TRACE_AT * seconds
         self.length = min(TRACE_SHARE * seconds, TRACE_MAX_S)
         self.log_dir = log_dir
+        self.counters = counters
+        self.every_tick = every_tick
+        self.at: dict[int, dict] = {}   # ticks done -> counters then
         self.k0 = self.k1 = None
         self.started = None
 
     def hook(self, now: float, ticks: list) -> None:
         if self.k0 is None and now >= self.start_at:
+            self.at[len(ticks)] = self.counters()
             jax.profiler.start_trace(self.log_dir)
             self.k0, self.started = len(ticks), time.perf_counter()
         elif (self.k0 is not None and self.k1 is None
               and now >= self.started + self.length):
-            jax.profiler.stop_trace()
-            self.k1 = len(ticks)
+            self._stop(ticks)
+        elif self.every_tick and self.k0 is not None and self.k1 is None:
+            self.at[len(ticks)] = self.counters()
+
+    def _stop(self, ticks: list) -> None:
+        jax.profiler.stop_trace()
+        self.k1 = len(ticks)
+        self.at[self.k1] = self.counters()
 
     def close(self, ticks: list) -> None:
         if self.k0 is not None and self.k1 is None:
-            jax.profiler.stop_trace()
-            self.k1 = len(ticks)
+            self._stop(ticks)
+
+    def tick_counters(self) -> list[dict]:
+        """The counters' change over each traced tick."""
+        return [_delta(self.at[j], self.at[j + 1])
+                for j in range(self.k0, self.k1)]
+
+
+def _delta(a: dict, b: dict) -> dict:
+    """``b - a`` for every counter of either (a missing series is 0)."""
+    return {k: b.get(k, 0.0) - a.get(k, 0.0) for k in {**a, **b}}
 
 
 def _say(err, line: str) -> None:
@@ -128,12 +151,14 @@ def run_cell(workload: str, *, seed: int, seconds: float, trace: bool,
 
     log_dir = tempfile.mkdtemp(prefix="bench-trace-") if trace else None
     prof = None
+    tally = counts.Counts(cell.reference)
     before = compiles.n
     try:
         def hook(now, ticks):
             nonlocal prof
             if prof is None:
-                prof = _Profiler(now, seconds, log_dir)
+                prof = _Profiler(now, seconds, log_dir, system.counters,
+                                 every_tick=tally.per_tick_counters)
             prof.hook(now, ticks)
 
         res = window.run(system, specs, seconds=seconds,
@@ -159,13 +184,15 @@ def run_cell(workload: str, *, seed: int, seconds: float, trace: bool,
     _say(err, f"window {res.seconds:.3f} s: {len(res.ticks)} ticks, "
               f"{res.delivered} tokens delivered, "
               f"{sum(c.done for c in res.clients.values())}/"
-              f"{len(res.clients)} requests finished, counters "
+              f"{len(res.clients)} requests finished, backlog "
+              f"{res.backlog(res.t0 + res.seconds / 2)} at mid-window and "
+              f"{res.backlog(res.t1)} at the close, counters "
               f"{res.counters_end}")
     _say(err, f"generator lateness (s): mean {sum(late) / len(late):.4f} "
               f"p99 {percentile(late, 99):.4f} max {late[-1]:.4f}; "
               f"compile events inside the window: {in_window}")
 
-    rec = _record(cell, m, res, setup_s, dev, reduced, prof)
+    rec = _record(cell, tally, m, res, setup_s, dev, reduced, prof)
     if reduced is not None:
         _say(err, f"trace: slice {reduced['slice_s']:.4f} s, busy "
                   f"{reduced['busy_s']:.4f} s, programs "
@@ -205,12 +232,12 @@ def run_cell(workload: str, *, seed: int, seconds: float, trace: bool,
     return result
 
 
-def _record(cell, m, res, setup_s, dev, reduced, prof) -> dict:
-    """What the metric readers read."""
-    flops = 0.0
-    for t in res.ticks:
-        flops += sum(counts.prefill_flops(m, p) for p, _ in t.prefills)
-        flops += sum(counts.decode_flops(m, a) for a in t.decoded)
+def _record(cell, tally, m, res, setup_s, dev, reduced, prof) -> dict:
+    """What the metric readers read.  ``tally`` is the configuration's
+    ``counts.Counts``; with a trace, ``counters_start`` and
+    ``counters_stop`` are the program's counters at the slice's two ends
+    (``adapter.System.counters``)."""
+    flops = counts.window_flops(tally, m, res.ticks)
     ttft = [(c.first if c.first is not None else res.t1) - c.due
             for c in res.clients.values()]
     itl = [g for c in res.clients.values() for g in c.gaps]
@@ -224,13 +251,16 @@ def _record(cell, m, res, setup_s, dev, reduced, prof) -> dict:
                         "prefill": adapter.PREFILL_PROGRAM}}
     if reduced is not None:
         traced = res.ticks[prof.k0:prof.k1]
+        per_tick = prof.tick_counters() if tally.per_tick_counters else None
         rec["trace"] = dict(
             reduced,
             decode_ticks=sum(bool(t.decoded) for t in traced),
-            decode_least_bytes=sum(counts.decode_least_bytes(m, t.decoded)
-                                   for t in traced if t.decoded),
+            decode_least_bytes=counts.slice_least_bytes(tally, m, traced,
+                                                        per_tick),
             prefill_padded_tokens=sum(s for t in traced
-                                      for _, s in t.prefills))
+                                      for _, s in t.prefills),
+            counters_start=prof.at[prof.k0],
+            counters_stop=prof.at[prof.k1])
     return rec
 
 

@@ -3,13 +3,53 @@ shapes alone (``configs/<config>.json`` ``model``), never from the
 program: the count is of the algorithm, so no implementation can push a
 share computed from it past its peak.
 
-Covers the dense decoder family the configurations use: GQA attention
-with rotary positions, a SwiGLU MLP, an optional RMSNorm scale, tied or
-untied output head.
+Covers the dense decoder family: GQA attention with rotary positions, a
+SwiGLU MLP, an optional RMSNorm scale, tied or untied output head.  A
+configuration of another architecture brings its own counts in its
+module (``Counts``; the contract is in ``spec.py``).
 """
 from __future__ import annotations
 
 BF16_BYTES = 2
+
+
+class Counts:
+    """The counts of one configuration: each of ``prefill_flops``,
+    ``decode_flops`` and ``decode_least_bytes`` that its module
+    (``configs/<config>.py``) defines, else the dense one of this file.
+    A module's own ``decode_least_bytes`` also takes the change of the
+    program's counters over the tick (``per_tick_counters``)."""
+
+    def __init__(self, module):
+        self.prefill_flops = getattr(module, "prefill_flops", prefill_flops)
+        self.decode_flops = getattr(module, "decode_flops", decode_flops)
+        self._least_bytes = getattr(module, "decode_least_bytes", None)
+        self.per_tick_counters = self._least_bytes is not None
+
+    def decode_least_bytes(self, m: dict, attended: list[int],
+                           counters: dict | None) -> float:
+        if self._least_bytes is None:
+            return decode_least_bytes(m, attended)
+        return self._least_bytes(m, attended, counters)
+
+
+def window_flops(c: Counts, m: dict, ticks) -> float:
+    """Forward FLOPs of the true prompt tokens prefilled and of every
+    decode token computed over ``ticks``."""
+    flops = 0.0
+    for t in ticks:
+        flops += sum(c.prefill_flops(m, p) for p, _ in t.prefills)
+        flops += sum(c.decode_flops(m, a) for a in t.decoded)
+    return flops
+
+
+def slice_least_bytes(c: Counts, m: dict, ticks, counters=None) -> float:
+    """Least HBM bytes of the decode ticks among ``ticks``; ``counters``
+    holds the program counters' change over each tick where the
+    configuration's count reads them."""
+    counters = counters or [None] * len(ticks)
+    return sum(c.decode_least_bytes(m, t.decoded, d)
+               for t, d in zip(ticks, counters) if t.decoded)
 
 
 def _dims(m: dict):

@@ -4,13 +4,35 @@ Everything that belongs to one configuration, traffic mix, cell or metric
 is a file of its own, found by name:
 
 * ``configs/<config>.json``: the model as it is run (program field names
-  under ``model``), its published source, ``reduced``, ``assumed`` and the
-  deployment (workers, slots, cache) it stands for;
+  under ``model``), its published source, ``reduced``, ``assumed``, the
+  deployment (workers, slots, cache) it stands for, and ``tiny``: the
+  ``model`` fields the CPU tests (``tests/``) change to run it small;
 * ``configs/<config>.py``: that configuration's plain float32 reference
-  (``init`` makes the weights from the seed, ``forward`` gives logits);
+  (``init`` makes the weights from the seed, ``forward`` gives logits),
+  and the counts of its architecture where ``harness/counts.py``'s dense
+  ones do not hold, each optional (``counts.Counts``):
+
+  - ``prefill_flops(m, prompt_len)``: forward FLOPs of one prefill of the
+    true prompt, one logits row;
+  - ``decode_flops(m, attended)``: forward FLOPs of one decoded token that
+    attends ``attended`` positions, its own included;
+  - ``decode_least_bytes(m, attended, counters)``: least HBM bytes of one
+    batched decode tick over live slots that attend ``attended``
+    positions each; ``counters`` is the change of every program counter
+    over that tick (``adapter.System.counters``: the harness's ten and
+    each series of the program's registry as ``family{label=value}``), so
+    that a count only the program knows (say, the experts that computed a
+    token) can enter.  Where it is defined, the traced run reads the
+    counters at every tick of its slice.
+
+  ``m`` is the configuration's ``model`` merged with its ``reference``;
 * ``traffic/<mix>.json``: the parameters the one generator reads;
 * ``cells/<workload>.json``: the cell's offered rate and its limits;
-* ``metrics/<metric>.py``: a reader ``read(record) -> float | None``;
+* ``metrics/<metric>.py``: a reader ``read(record) -> float | None``
+  (``cellrun._record`` says what a record holds: among others the
+  counters at the middle and end of the window, and in a traced run at
+  the slice's two ends, and the device seconds of each program and of
+  each XLA op in the slice);
 * ``peaks.json``: the device peaks, keyed by ``device_kind``.
 
 A later cell, configuration, mix or metric is added as new files and new

@@ -14,7 +14,11 @@ trace (``tests/data/trace-olmo-1b.json.gz``, cut from a chip run of
   annotation to the end of the last), the device's busy time in it (the
   union of the op intervals), the executions and device seconds of each
   program, the longest idle gaps with the annotation the host was inside
-  during each, and the programs that took the most device time.
+  during each, the programs that took the most device time, and the
+  device seconds of each XLA op by name (``op_seconds``), so that a
+  kernel's reader finds its own time inside a program.  Ops nest (a
+  ``while`` holds the ops of its body), so these do not add up to the
+  busy time.
 """
 from __future__ import annotations
 
@@ -86,11 +90,15 @@ def reduce(events: dict, top: int = 10) -> dict:
             p = programs[program_name(name)]
             p["count"] += 1
             p["device_s"] += d * 1e-9
+    op_seconds: dict[str, float] = collections.defaultdict(float)
+    for name, s, d in events["ops"]:
+        if s >= lo and s + d <= hi:
+            op_seconds[name] += d * 1e-9
     gaps = sorted(gaps_between(spans, lo, hi), key=lambda g: g[0] - g[1])
     idle = [[_host_at(host, (s + e) / 2), (e - s) * 1e-9]
             for s, e in gaps[:top]]
     device_ops = sorted(([n, p["device_s"]] for n, p in programs.items()),
                         key=lambda x: -x[1])[:top]
     return {"slice_s": (hi - lo) * 1e-9, "busy_s": busy_ns * 1e-9,
-            "programs": dict(programs),
+            "programs": dict(programs), "op_seconds": dict(op_seconds),
             "breakdown": {"device_ops": device_ops, "idle_gaps": idle}}

@@ -59,6 +59,11 @@ class WindowResult:
     def seconds(self) -> float:
         return self.t1 - self.t0
 
+    def backlog(self, t: float) -> int:
+        """Requests due by ``t`` and not finished by ``t``."""
+        return sum(1 for c in self.clients.values()
+                   if c.due <= t and not (c.done and c.last <= t))
+
 
 def _annotate(name):
     return jax.profiler.TraceAnnotation(name)

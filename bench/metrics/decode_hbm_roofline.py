@@ -1,7 +1,8 @@
 """Share of the HBM roofline reached by the batched decode program: the
-least bytes its ticks in the traced slice needed (harness/counts.py:
-weights once in bf16, each live slot's keys and values, one new row per
-slot) over its device time in the trace times the peak bandwidth."""
+least bytes its ticks in the traced slice needed (the configuration's
+counts; harness/counts.py's dense ones: weights once in bf16, each live
+slot's keys and values, one new row per slot) over its device time in
+the trace times the peak bandwidth."""
 
 
 def read(rec):

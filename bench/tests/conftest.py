@@ -11,16 +11,27 @@ import pytest
 BENCH = Path(__file__).resolve().parents[1]
 sys.path.insert(0, str(BENCH))
 
-TINY = {
-    "deepseek-coder-33b": {"n_layers": 2, "d_model": 64, "n_heads": 4,
-                           "n_kv_heads": 2, "d_ff": 128, "vocab_size": 256},
-}
+# every configuration of the benchmark, found by its file, and the model
+# fields it changes to run small on the CPU (its ``tiny``)
+CONFIGS = sorted(p.stem for p in (BENCH / "configs").glob("*.json"))
+TINY = {c: json.loads((BENCH / "configs" / f"{c}.json").read_text())["tiny"]
+        for c in CONFIGS}
 MIX = {"interarrival": {"dist": "gamma", "shape": 1.0},
        "prompt_len": {"dist": "lognormal", "median": 24, "sigma": 0.8,
                       "min": 8, "max": 64},
        "output_len": {"dist": "lognormal", "median": 8, "sigma": 0.5,
                       "min": 4, "max": 16},
        "schedule_seed": 0, "env": "normal", "policy": "crch"}
+
+
+def limit_of(config: str) -> float:
+    """The widest logit gap the cells of ``config`` allow (any one: the
+    cells of one configuration share it)."""
+    bench = json.loads((BENCH.parent / "BENCHMARK.json").read_text())
+    cell = next(w["name"] for w in bench["workloads"]
+                if w["config"] == config)
+    return json.loads((BENCH / "cells" / f"{cell}.json").read_text()
+                      )["check"]["max_logit_gap"]
 
 
 def make_bench(root: Path, config: str, *, rate_rps=20.0, limit=0.25,

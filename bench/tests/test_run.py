@@ -13,21 +13,16 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from conftest import BENCH
+from conftest import BENCH, CONFIGS, limit_of
 from harness.cellrun import run_cell
 
 sys.path.insert(0, str(BENCH.parent / "src"))
 from repro.distributed.steps import make_serve_step  # noqa: E402
 
-# the limit the cells hold the widest gap to
-LIMIT = json.loads((BENCH / "cells" /
-                    "deepseek-coder-33b.chat-saturated.json"
-                    ).read_text())["check"]["max_logit_gap"]
-
 
 def _run(tiny_bench, config, break_path=None, seed=2**31 + 17,
          control=False):
-    bench, root = tiny_bench(config, limit=LIMIT)
+    bench, root = tiny_bench(config, limit=limit_of(config))
     out, err = io.StringIO(), io.StringIO()
     res = run_cell(f"{config}.tiny", seed=seed, seconds=2.5, trace=False,
                    t_proc=time.time(), bench=bench, bench_dir=root,
@@ -63,7 +58,7 @@ def _altered_token(system):
     e._serve = serve
 
 
-@pytest.mark.parametrize("config", ["deepseek-coder-33b"])
+@pytest.mark.parametrize("config", CONFIGS)
 def test_sound_run_is_correct(tiny_bench, config):
     res = _run(tiny_bench, config)
     assert res["correct"], res["checks"]
@@ -72,23 +67,25 @@ def test_sound_run_is_correct(tiny_bench, config):
 
 
 @pytest.mark.parametrize("fault", [_stale_cache, _altered_token])
-@pytest.mark.parametrize("config", ["deepseek-coder-33b"])
+@pytest.mark.parametrize("config", CONFIGS)
 def test_fault_makes_the_run_incorrect(tiny_bench, config, fault):
     res = _run(tiny_bench, config, break_path=fault)
     assert not res["correct"], res["checks"]
-    assert res["checks"]["max_logit_gap"]["value"] > LIMIT
+    assert res["checks"]["max_logit_gap"]["value"] > limit_of(config)
 
 
-def test_control_in_the_programs_place_is_incorrect(tiny_bench):
-    res = _run(tiny_bench, "deepseek-coder-33b", control=True)
+@pytest.mark.parametrize("config", CONFIGS)
+def test_control_in_the_programs_place_is_incorrect(tiny_bench, config):
+    res = _run(tiny_bench, config, control=True)
     assert not res["correct"], res["checks"]
-    assert res["checks"]["max_logit_gap"]["value"] > LIMIT
+    assert res["checks"]["max_logit_gap"]["value"] > limit_of(config)
 
 
-def test_traced_run_reports_per_layer_metrics(tiny_bench):
-    bench, root = tiny_bench("deepseek-coder-33b", limit=LIMIT)
+@pytest.mark.parametrize("config", CONFIGS)
+def test_traced_run_reports_per_layer_metrics(tiny_bench, config):
+    bench, root = tiny_bench(config, limit=limit_of(config))
     out = io.StringIO()
-    res = run_cell("deepseek-coder-33b.tiny", seed=5, seconds=2.5, trace=True,
+    res = run_cell(f"{config}.tiny", seed=5, seconds=2.5, trace=True,
                    t_proc=time.time(), bench=bench, bench_dir=root,
                    require_accelerator=False, out=out, err=io.StringIO())
     # the CPU has no device plane: only the counter-based metrics read

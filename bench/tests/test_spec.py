@@ -56,6 +56,14 @@ def test_configs():
         cfg = json.loads((BENCH.parent / c["file"]).read_text())
         assert set(c["reduced"]) == set(cfg["reduced"])
         assert set(cfg["reduced"]) <= set(cfg["published"])
+        # the CPU tests' sizes change model fields only
+        assert set(cfg["tiny"]) <= set(cfg["model"])
+        # the cells of one configuration share its correctness limit
+        limits = {json.loads((BENCH / "cells" / f"{w['name']}.json")
+                             .read_text())["check"]["max_logit_gap"]
+                  for w in BENCHMARK["workloads"]
+                  if w["config"] == c["name"]}
+        assert len(limits) == 1
 
 
 def test_unknown_device_kind_is_an_error():

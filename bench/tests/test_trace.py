@@ -60,3 +60,43 @@ def test_recorded_trace():
         "serve_step_masked", "prefill_last_idx_step", "_lambda",
         "convert_element_type", "dynamic_slice", "squeeze"}
     assert sum(s for _, s in top) == pytest.approx(mod_s)
+
+
+# ``reduce``'s outputs before it gave ``op_seconds``, on both recorded
+# traces, at the run's ``top`` and at one that keeps everything
+GOLDEN = Path(__file__).parent / "data" / "reduce-golden.json"
+
+
+@pytest.mark.parametrize("config", ["deepseek-coder-33b", "olmo-1b"])
+def test_op_seconds_beside_the_old_outputs(config):
+    with gzip.open(Path(__file__).parent / "data" /
+                   f"trace-{config}.json.gz", "rt") as f:
+        ev = json.load(f)
+    golden = json.loads(GOLDEN.read_text())[config]
+    for key, top in (("top10", 10), ("all", 10_000)):
+        r = trace.reduce(ev, top=top)
+        ops = r.pop("op_seconds")
+        assert json.dumps(r, sort_keys=True) == \
+            json.dumps(golden[key], sort_keys=True)
+    lo = min(s for _, s, _ in ev["host"])
+    hi = max(s + d for _, s, d in ev["host"])
+    want = {}
+    for name, s, d in ev["ops"]:
+        if lo <= s and s + d <= hi:
+            want[name] = want.get(name, 0.0) + d * 1e-9
+    assert ops == pytest.approx(want, rel=1e-12) and len(ops) > 100
+    assert all(n.startswith("%") for n in ops)
+    # ops nest (a loop holds its body's ops): each one alone fits in the
+    # busy time, all of them together need not
+    assert 0 < max(ops.values()) <= r["busy_s"] + 1e-12
+
+
+def test_op_seconds_synthetic():
+    ev = {"host": [["bench.step", 10, 100]],
+          "modules": [["jit_serve_step_masked(2)", 20, 80]],
+          "ops": [["%while.1", 20, 60], ["%fusion.2", 25, 10],
+                  ["%fusion.2", 40, 10], ["%custom-call.3", 85, 10],
+                  ["%early", 0, 15], ["%late", 105, 10]]}
+    r = trace.reduce(ev)
+    assert r["op_seconds"] == pytest.approx(
+        {"%while.1": 60e-9, "%fusion.2": 20e-9, "%custom-call.3": 10e-9})

@@ -99,3 +99,19 @@ def test_waiting_requests_and_open_stalls_count():
     assert res.clients[1].first is None
     assert res.clients[0].gaps == pytest.approx([res.t1 - (res.t0 + 2.0)])
     assert res.lateness[1] >= 0.0
+
+
+def test_backlog_counts_requests_due_and_not_finished():
+    clock = Clock()
+    v = lambda rid, n: SlotView(rid, 0, n, 10 + n)  # noqa: E731
+    script = [({0: v(0, 1)}, {}), ({1: v(1, 1)}, {0: 3}), ({1: v(1, 2)}, {}),
+              ({}, {1: 3})]
+    sysm = Scripted(clock, script)
+    specs = [_spec(0, 0.0), _spec(1, 0.5), _spec(2, 2.5)]
+    res = window.run(sysm, specs, seconds=6.0, clock=clock,
+                     sleep=clock.sleep)
+    t0 = res.t0
+    # request 0 finishes in the tick ending at 2 s, request 1 at 4 s;
+    # request 2 is due at 2.5 s and never served
+    assert [res.backlog(t0 + t) for t in (0.0, 1.0, 2.0, 3.0, 4.0, 6.0)] \
+        == [1, 2, 1, 2, 1, 1]

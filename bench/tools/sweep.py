@@ -22,11 +22,6 @@ from harness.cellrun import build  # noqa: E402
 from harness.stats import percentile  # noqa: E402
 
 
-def backlog(res, t: float) -> int:
-    return sum(1 for c in res.clients.values()
-               if c.due <= t and not (c.done and c.last <= t))
-
-
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--workload", required=True)
@@ -54,8 +49,8 @@ def main() -> None:
                 "setup_s": setup, "window_s": res.seconds,
                 "due": len(res.clients),
                 "finished": sum(c.done for c in res.clients.values()),
-                "backlog_mid": backlog(res, res.t0 + res.seconds / 2),
-                "backlog_end": backlog(res, res.t1),
+                "backlog_mid": res.backlog(res.t0 + res.seconds / 2),
+                "backlog_end": res.backlog(res.t1),
                 "delivered_tok_s": res.delivered / res.seconds,
                 "ttft_p50_s": percentile(ttft, 50),
                 "ttft_p90_s": percentile(ttft, 90),

@@ -190,7 +190,8 @@ def run(workload: str, *, seed: int, seconds: float, bench=None,
     def hook(now, ticks):
         nonlocal prof
         if prof is None:
-            prof = cellrun._Profiler(now, seconds, log_dir)
+            prof = cellrun._Profiler(now, seconds, log_dir,
+                                     system.counters)
         prof.hook(now, ticks)
 
     try:
