@@ -22,6 +22,7 @@ import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.models.config import ModelConfig
+from repro.models.lm import layer_stacks
 
 # trailing-dims spec by (parent, leaf-name); "." matches any parent
 _RULES: dict[tuple[str, str], tuple] = {
@@ -186,12 +187,18 @@ def batch_specs(abstract_batch, mesh: Mesh):
 def cache_specs(abstract_cache, cfg: ModelConfig, mesh: Mesh):
     """KV caches: (L, B, S, KV, D) -> batch on data, seq on model.
     Recurrent states: channel dims on model."""
+    prefixes = [prefix for _, prefix, _ in layer_stacks(cfg) if prefix]
+
     def one(path, leaf):
         names = _path_names(path)
         name = names[-1] if names else ""
+        for prefix in prefixes:       # each stack's leaves shard alike
+            name = name.removeprefix(prefix)
         if name in ("k", "v", "cross_k", "cross_v"):
             lead = leaf.ndim - 4                       # stacked layer axes
             parts = [None] * lead + ["data", "model", None, None]
+        elif name in ("c_kv", "k_pe"):                 # MLA (L, B, S, C)
+            parts = [None, "data", "model", None]
         elif name == "S":                              # rwkv state (L,B,H,N,N)
             parts = [None, "data", "model", None, None]
         elif name in ("x_tm", "x_cm"):                 # (L, B, D)

@@ -82,7 +82,12 @@ def make_serve_step(cfg: ModelConfig, *, cache_axes=None):
     idle slots' stale ``last_token``/``pos`` would silently rewrite cache
     rows every tick: harmless for dense KV only because prefill overwrites
     the whole row on reuse, but fatal for recurrent (RWKV / RG-LRU) state,
-    which accumulates."""
+    which accumulates.
+
+    For an MoE model the masked step's middle output is ``(logits,
+    moe_counts)``: ``moe_counts`` (2,) int32 holds, summed over the expert
+    layers, the held experts that computed at least one live token and
+    the live token -> held-expert assignments (``decode_moe_counts``)."""
 
     def serve_step(params, cache, tokens, pos):
         logits, cache = lm.decode_step(params, cfg, cache, tokens, pos)
@@ -93,7 +98,8 @@ def make_serve_step(cfg: ModelConfig, *, cache_axes=None):
         return serve_step
 
     def serve_step_masked(params, cache, tokens, pos, live):
-        logits, new_cache = lm.decode_step(params, cfg, cache, tokens, pos)
+        logits, new_cache, routes = lm.decode_step(
+            params, cfg, cache, tokens, pos, return_routes=True)
 
         def commit(new, old, axis):
             shape = [1] * new.ndim
@@ -102,9 +108,20 @@ def make_serve_step(cfg: ModelConfig, *, cache_axes=None):
 
         new_cache = jax.tree.map(commit, new_cache, cache, cache_axes)
         nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)[:, None]
-        return nxt, logits, new_cache
+        if routes is None:
+            return nxt, logits, new_cache
+        return nxt, (logits, decode_moe_counts(routes, live)), new_cache
 
     return serve_step_masked
+
+
+def decode_moe_counts(routes, live):
+    """``routes`` (L_moe, B, E_held) bool, ``live`` (B,) bool -> (2,)
+    int32: held experts that computed at least one live token, and live
+    token -> held-expert assignments, each summed over the layers."""
+    hit = routes & live[None, :, None]
+    return jnp.stack([jnp.sum(jnp.any(hit, axis=1)),
+                      jnp.sum(hit)]).astype(jnp.int32)
 
 
 def make_prefill_step(cfg: ModelConfig, cache_len: int, *,

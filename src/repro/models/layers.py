@@ -66,11 +66,14 @@ def apply_norm(cfg: ModelConfig, params, x, eps: float = 1e-6):
 # rotary position embedding
 # ---------------------------------------------------------------------------
 
-def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
-    """x: (B, S, H, D); positions: (B, S) int32."""
+def rope(x: jax.Array, positions: jax.Array, theta: float,
+         inv_freq: jax.Array | None = None) -> jax.Array:
+    """x: (B, S, H, D); positions: (B, S) int32.  Rotate-half form;
+    ``inv_freq`` (D/2,) replaces the plain ``theta`` frequencies."""
     d = x.shape[-1]
     half = d // 2
-    freq = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    freq = (theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+            if inv_freq is None else inv_freq)
     angles = positions[..., None].astype(jnp.float32) * freq  # (B, S, half)
     cos = jnp.cos(angles)[:, :, None, :]
     sin = jnp.sin(angles)[:, :, None, :]
@@ -79,12 +82,62 @@ def rope(x: jax.Array, positions: jax.Array, theta: float) -> jax.Array:
     return out.astype(x.dtype)
 
 
+def yarn_mscale(factor: float, mscale: float) -> float:
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
+
+
+def yarn_ramp(cfg: ModelConfig) -> tuple[int, int]:
+    """First and last rotary frequency index of YaRN's ramp between the
+    extrapolated and the interpolated frequencies (DeepSeek-V2's
+    ``yarn_find_correction_range``)."""
+    dim, base = cfg.qk_rope_head_dim, cfg.rope_theta
+
+    def corr(rotations):
+        return (dim * math.log(cfg.yarn_original_max_pos
+                               / (rotations * 2 * math.pi))
+                / (2 * math.log(base)))
+
+    low = max(math.floor(corr(cfg.yarn_beta_fast)), 0)
+    high = min(math.ceil(corr(cfg.yarn_beta_slow)), dim - 1)
+    return low, high
+
+
+def yarn_inv_freq(cfg: ModelConfig) -> jax.Array:
+    """YaRN inverse frequencies of the ``qk_rope_head_dim`` rotary dims:
+    the plain ones below the ramp, divided by ``yarn_factor`` above it,
+    blended linearly across it."""
+    dim, base = cfg.qk_rope_head_dim, cfg.rope_theta
+    extra = 1.0 / base ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim)
+    inter = extra / cfg.yarn_factor
+    low, high = yarn_ramp(cfg)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(dim // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    return inter * ramp + extra * (1.0 - ramp)
+
+
+def mla_softmax_scale(cfg: ModelConfig) -> float:
+    """1/sqrt(query width), times YaRN's mscale squared."""
+    scale = (cfg.qk_nope_head_dim + cfg.qk_rope_head_dim) ** -0.5
+    if cfg.yarn_factor:
+        scale *= yarn_mscale(cfg.yarn_factor, cfg.yarn_mscale) ** 2
+    return scale
+
+
+def _mla_rope(cfg: ModelConfig, x, positions):
+    inv = yarn_inv_freq(cfg) if cfg.yarn_factor else None
+    return rope(x, positions, cfg.rope_theta, inv)
+
+
 # ---------------------------------------------------------------------------
 # attention (GQA, full / local-window / cross; train + decode paths)
 # ---------------------------------------------------------------------------
 
 def init_attention(key, cfg: ModelConfig, *, d_model: int | None = None,
                    n_heads: int | None = None, n_kv: int | None = None):
+    if cfg.attention == "mla":
+        return _init_mla(key, cfg)
     d = d_model or cfg.d_model
     h = n_heads or cfg.n_heads
     kv = n_kv or cfg.n_kv_heads
@@ -119,17 +172,19 @@ def _project_qkv(p, x, cfg: ModelConfig, n_heads, n_kv, dtype):
     return q, k, v
 
 
-def _sdpa(q, k, v, mask, *, q_chunk: int = 1024):
+def _sdpa(q, k, v, mask, *, q_chunk: int = 1024, scale: float | None = None):
     """Chunked scaled-dot-product attention (GQA) with fp32 softmax.
 
-    q: (B, Sq, H, D); k, v: (B, Sk, KV, D); mask(q_pos, k_pos) callable
-    returning a boolean (Bq, Sk) block, or None.  Scanning over query chunks
-    keeps the live score tensor at (B, H, q_chunk, Sk).
+    q, k: (B, Sq|Sk, H|KV, D); v: (B, Sk, KV, Dv); mask(q_pos, k_pos)
+    callable returning a boolean (Bq, Sk) block, or None.  ``scale``
+    defaults to 1/sqrt(D).  Scanning over query chunks keeps the live score
+    tensor at (B, H, q_chunk, Sk).
     """
     b, sq, h, d = q.shape
     sk, kv = k.shape[1], k.shape[2]
+    dv = v.shape[-1]
     g = h // kv
-    scale = 1.0 / math.sqrt(d)
+    scale = 1.0 / math.sqrt(d) if scale is None else scale
     qg = q.reshape(b, sq, kv, g, d)
     # (B, KV, G, Sq, Sk) einsum operands
     kT = k.transpose(0, 2, 3, 1)                      # (B, KV, D, Sk)
@@ -169,15 +224,20 @@ def _sdpa(q, k, v, mask, *, q_chunk: int = 1024):
             return None, block(qq, pp)
 
         _, outs = jax.lax.scan(body, None, (qb, pos))
-        out = outs.transpose(1, 0, 2, 3, 4, 5).reshape(b, sq, kv, g, d)
-    return out.reshape(b, sq, h, d)
+        out = outs.transpose(1, 0, 2, 3, 4, 5).reshape(b, sq, kv, g, dv)
+    return out.reshape(b, sq, h, dv)
 
 
 def attention_forward(p, x, cfg: ModelConfig, *, positions, mode: str,
                       window: int = 0, n_heads=None, n_kv=None,
                       context=None, q_chunk: int = 1024,
                       return_kv: bool = False):
-    """mode: causal | local | bidir | cross (context = encoder output)."""
+    """mode: causal | local | bidir | cross (context = encoder output).
+    With ``return_kv`` also the rows the decode cache keeps (GQA: keys and
+    values; MLA: the latent and the roped key)."""
+    if cfg.attention == "mla":
+        return mla_forward(p, x, cfg, positions=positions, q_chunk=q_chunk,
+                           return_kv=return_kv)
     dtype = x.dtype
     h = n_heads or cfg.n_heads
     kv = n_kv or cfg.n_kv_heads
@@ -224,7 +284,10 @@ def attention_decode(p, x, cache, cfg: ModelConfig, *, pos, window: int = 0,
     per-row (B,) vector (continuous batching: every slot decodes at its own
     position).  For ``window>0`` the cache is a rolling buffer of length
     ``window``.  ``cross_kv`` short-circuits to cross-attention against
-    precomputed encoder K/V."""
+    precomputed encoder K/V.  An MLA layer's cache is {"c_kv", "k_pe"}
+    (``mla_decode``)."""
+    if cfg.attention == "mla":
+        return mla_decode(p, x, cache, cfg, pos=pos)
     dtype = x.dtype
     h = n_heads or cfg.n_heads
     kv = n_kv or cfg.n_kv_heads
@@ -293,6 +356,106 @@ def attention_decode(p, x, cache, cfg: ModelConfig, *, pos, window: int = 0,
 
 
 # ---------------------------------------------------------------------------
+# multi-head latent attention (DeepSeek-V2, arXiv:2405.04434 section 2.1)
+# ---------------------------------------------------------------------------
+
+def _init_mla(key, cfg: ModelConfig):
+    d, h, c = cfg.d_model, cfg.n_heads, cfg.kv_lora_rank
+    nope, r, vd = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    ks = _split(key, 4)
+    return {
+        "wq": dense_init(ks[0], (d, h * (nope + r))),
+        "wkv_a": dense_init(ks[1], (d, c + r)),
+        "kv_norm": {"scale": jnp.ones((c,), jnp.float32)},
+        "wkv_b": dense_init(ks[2], (c, h * (nope + vd))),
+        "wo": dense_init(ks[3], (h * vd, d)),
+    }
+
+
+def _mla_project(p, x, cfg: ModelConfig, positions):
+    """Per-head queries (nope part, roped part), the normalised latent and
+    the roped key shared by all heads: (B,S,H,nope), (B,S,H,r), (B,S,C),
+    (B,S,r)."""
+    b, s, _ = x.shape
+    dtype = x.dtype
+    h, c = cfg.n_heads, cfg.kv_lora_rank
+    nope, r = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+    q = (x @ p["wq"].astype(dtype)).reshape(b, s, h, nope + r)
+    kv_a = x @ p["wkv_a"].astype(dtype)
+    c_kv = apply_norm(cfg, p["kv_norm"], kv_a[..., :c])
+    k_pe = _mla_rope(cfg, kv_a[..., c:].reshape(b, s, 1, r), positions)
+    q_pe = _mla_rope(cfg, q[..., nope:], positions)
+    return q[..., :nope], q_pe, c_kv, k_pe[:, :, 0]
+
+
+def mla_forward(p, x, cfg: ModelConfig, *, positions, q_chunk: int = 1024,
+                return_kv: bool = False):
+    """Causal MLA over a whole sequence (prefill, training): keys and
+    values expanded per head from the latent, ``W_kv_b``."""
+    with jax.named_scope("mla"):
+        b, s, _ = x.shape
+        dtype = x.dtype
+        h, nope, vd = cfg.n_heads, cfg.qk_nope_head_dim, cfg.v_head_dim
+        q_nope, q_pe, c_kv, k_pe = _mla_project(p, x, cfg, positions)
+        kv = (c_kv @ p["wkv_b"].astype(dtype)).reshape(b, s, h, nope + vd)
+        q = jnp.concatenate([q_nope, q_pe], -1)
+        k = jnp.concatenate(
+            [kv[..., :nope],
+             jnp.broadcast_to(k_pe[:, :, None], (*k_pe.shape[:2], h,
+                                                 k_pe.shape[-1]))], -1)
+        kpos = jnp.arange(s)
+        q = constrain(q, ("batch", "seq", None, None))
+        out = _sdpa(q, k, kv[..., nope:],
+                    lambda qp: qp[:, None] >= kpos[None, :],
+                    q_chunk=q_chunk, scale=mla_softmax_scale(cfg))
+        out = out.reshape(b, s, h * vd) @ p["wo"].astype(dtype)
+        out = constrain(out, ("batch", "seq", "embed"))
+    if return_kv:
+        return out, (c_kv, k_pe)
+    return out
+
+
+def mla_decode(p, x, cache, cfg: ModelConfig, *, pos):
+    """One-token MLA decode, absorbed: ``W_uk`` folds into the query and
+    ``W_uv`` into the output, so scores and values are taken against the
+    cached latent itself, never expanded over the cache.  cache =
+    {"c_kv": (B, S, C), "k_pe": (B, S, r)}; ``pos`` a scalar or (B,)."""
+    with jax.named_scope("mla"):
+        dtype = x.dtype
+        b = x.shape[0]
+        h, c = cfg.n_heads, cfg.kv_lora_rank
+        nope, vd = cfg.qk_nope_head_dim, cfg.v_head_dim
+        posb = jnp.broadcast_to(jnp.reshape(pos, (-1, 1)), (b, 1)
+                                ).astype(jnp.int32)
+        q_nope, q_pe, c_new, kpe_new = _mla_project(p, x, cfg, posb)
+
+        def upd(cc, u, at):
+            return jax.lax.dynamic_update_slice(cc, u.astype(cc.dtype),
+                                                (at, 0))
+
+        c_kv = jax.vmap(upd)(cache["c_kv"], c_new, posb[:, 0])
+        k_pe = jax.vmap(upd)(cache["k_pe"], kpe_new, posb[:, 0])
+        wkv_b = p["wkv_b"].astype(dtype).reshape(c, h, nope + vd)
+        q_lat = jnp.einsum("bhn,chn->bhc", q_nope[:, 0], wkv_b[..., :nope])
+        scores = (jnp.einsum("bhc,bsc->bhs", q_lat, c_kv.astype(dtype),
+                             preferred_element_type=jnp.float32)
+                  + jnp.einsum("bhr,bsr->bhs", q_pe[:, 0],
+                               k_pe.astype(dtype),
+                               preferred_element_type=jnp.float32))
+        scores = scores * mla_softmax_scale(cfg)
+        scores = constrain(scores, ("batch", None, "kv_seq"))
+        valid = jnp.arange(c_kv.shape[1])[None] <= posb        # (B, S)
+        scores = jnp.where(valid[:, None], scores, -1e30)
+        w = jax.nn.softmax(scores, axis=-1)
+        o_lat = jnp.einsum("bhs,bsc->bhc", w.astype(dtype),
+                           c_kv.astype(dtype),
+                           preferred_element_type=jnp.float32).astype(dtype)
+        o = jnp.einsum("bhc,chv->bhv", o_lat, wkv_b[..., nope:])
+        out = o.reshape(b, 1, h * vd) @ p["wo"].astype(dtype)
+    return out, {"c_kv": c_kv, "k_pe": k_pe}
+
+
+# ---------------------------------------------------------------------------
 # MLP (SwiGLU) and MoE
 # ---------------------------------------------------------------------------
 
@@ -334,25 +497,80 @@ def mlp_forward(p, x):
 
 def init_moe(key, cfg: ModelConfig):
     ks = _split(key, 4)
-    e, d, ff = cfg.n_experts, cfg.d_model, cfg.d_ff
-    return {
-        "router": dense_init(ks[0], (d, e)),
+    e, d, ff = cfg.n_experts, cfg.d_model, cfg.expert_d_ff
+    p = {
+        "router": dense_init(ks[0], (d, cfg.router_width)),
         "w_gate": dense_init(ks[1], (e, d, ff), in_axis=1),
         "w_up": dense_init(ks[2], (e, d, ff), in_axis=1),
         "w_down": dense_init(ks[3], (e, ff, d), in_axis=1),
     }
+    if cfg.n_shared_experts:
+        p["shared"] = init_mlp(jax.random.fold_in(key, 4), cfg,
+                               ff=cfg.n_shared_experts * ff)
+    return p
 
 
 MOE_GROUP = 2048  # tokens per dispatch group (GShard-style local capacity)
 
 
 def moe_forward(p, x, cfg: ModelConfig):
+    """The expert layer.  Returns (out, aux_loss, routes): ``routes``
+    (B, S, n_experts) bool marks the token -> held-expert assignments that
+    were computed."""
+    with jax.named_scope("moe"):
+        if cfg.moe_dropless:
+            return _moe_dropless(p, x, cfg)
+        return _moe_capacity(p, x, cfg)
+
+
+def _moe_dropless(p, x, cfg: ModelConfig):
+    """DeepSeekMoE as one chip of an expert-parallel deployment holds it.
+
+    A softmax router over all ``router_width`` experts picks the top-k
+    (greedy), gates optionally renormalised; this layer holds
+    experts ``[expert_offset, expert_offset + n_experts)`` and adds their
+    gate-weighted outputs: every held expert runs on every token and a
+    token's gate is 0 for a held expert it was not routed to, so no token
+    is ever dropped and what an absent expert would add is left out.  The
+    shared experts, one SwiGLU every token passes through, are added
+    whole."""
+    dtype = x.dtype
+    b, s, d = x.shape
+    xt = x.reshape(b * s, d)
+    logits = (xt @ p["router"].astype(dtype)).astype(jnp.float32)
+    probs = jax.nn.softmax(logits, axis=-1)                        # (N, E)
+    gate_vals, gate_idx = jax.lax.top_k(probs, cfg.top_k)          # (N, k)
+    if cfg.norm_topk_prob:
+        gate_vals = gate_vals / jnp.maximum(
+            jnp.sum(gate_vals, -1, keepdims=True), 1e-9)
+    held = cfg.expert_offset + jnp.arange(cfg.n_experts)
+    hit = gate_idx[:, :, None] == held                             # (N,k,e)
+    routes = jnp.any(hit, axis=1)                                  # (N, e)
+    gates = jnp.sum(jnp.where(hit, gate_vals[..., None], 0.0), axis=1)
+    gate = jax.nn.silu(jnp.einsum("nd,edf->nef", xt,
+                                  p["w_gate"].astype(dtype)))
+    up = jnp.einsum("nd,edf->nef", xt, p["w_up"].astype(dtype))
+    hidden = gate * up * gates[..., None].astype(dtype)
+    out = jnp.einsum("nef,efd->nd", hidden, p["w_down"].astype(dtype))
+    out = out.reshape(b, s, d)
+    if "shared" in p:
+        out = out + mlp_forward(p["shared"], x)
+    # load-balancing auxiliary loss (Switch) over the router's experts
+    me = jnp.mean(probs, axis=0)
+    ce = jnp.mean(jax.nn.one_hot(gate_idx, cfg.router_width).sum(1), axis=0)
+    aux = cfg.router_width * jnp.sum(me * ce)
+    return (constrain(out, ("batch", "seq", "embed")), aux,
+            routes.reshape(b, s, -1))
+
+
+def _moe_capacity(p, x, cfg: ModelConfig):
     """GShard-style grouped top-k dispatch with capacity.
 
     Tokens are dispatched within *groups* of <= MOE_GROUP tokens (per
     sequence slice), so the one-hot dispatch/combine tensors are
     (G_count, G, E, C_g) with C_g = ceil(G*k/E*cf) -- never the quadratic
-    (N, E, N*k/E) blow-up of a global dispatch.  Returns (out, aux_loss).
+    (N, E, N*k/E) blow-up of a global dispatch.  A token past its expert's
+    capacity is dropped.
     """
     dtype = x.dtype
     b, s, d = x.shape
@@ -367,8 +585,9 @@ def moe_forward(p, x, cfg: ModelConfig):
     logits = (xt @ p["router"].astype(dtype)).astype(jnp.float32)  # (B,G,E)
     probs = jax.nn.softmax(logits, axis=-1)
     gate_vals, gate_idx = jax.lax.top_k(probs, k)                  # (B,G,k)
-    gate_vals = gate_vals / jnp.maximum(
-        jnp.sum(gate_vals, -1, keepdims=True), 1e-9)
+    if cfg.norm_topk_prob:
+        gate_vals = gate_vals / jnp.maximum(
+            jnp.sum(gate_vals, -1, keepdims=True), 1e-9)
 
     cap = int(math.ceil(g * k / e * cfg.capacity_factor))
     cap = max(cap, 4)
@@ -394,4 +613,6 @@ def moe_forward(p, x, cfg: ModelConfig):
     me = jnp.mean(probs, axis=(0, 1))
     ce = jnp.mean(onehot.sum(2), axis=(0, 1))
     aux = e * jnp.sum(me * ce)
-    return constrain(out.reshape(b, s, d), ("batch", "seq", "embed")), aux
+    routes = jnp.any(onehot * keep[..., None] > 0, axis=2)         # (B,G,E)
+    return (constrain(out.reshape(b, s, d), ("batch", "seq", "embed")), aux,
+            routes.reshape(b, s, e))

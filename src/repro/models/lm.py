@@ -87,7 +87,7 @@ def chunked_xent(h, w_out, targets, mask, *, chunk: int = 512):
 # parameter init
 # ---------------------------------------------------------------------------
 
-def _init_dense_layer(key, cfg: ModelConfig):
+def _init_dense_layer(key, cfg: ModelConfig, moe: bool):
     ks = _split(key, 4)
     p = {
         "ln1": init_norm(cfg, cfg.d_model),
@@ -95,8 +95,8 @@ def _init_dense_layer(key, cfg: ModelConfig):
     }
     if cfg.block_type != "parallel":
         p["ln2"] = init_norm(cfg, cfg.d_model)
-    p["moe" if cfg.is_moe else "mlp"] = (
-        init_moe(ks[1], cfg) if cfg.is_moe else init_mlp(ks[1], cfg))
+    p["moe" if moe else "mlp"] = (
+        init_moe(ks[1], cfg) if moe else init_mlp(ks[1], cfg))
     return p
 
 
@@ -181,8 +181,15 @@ def init_params(key, cfg: ModelConfig):
         params["layers"] = _stack_init(
             ks[3], cfg.n_layers, lambda k: _init_cross_layer(k, cfg))
     else:
+        # leading dense layers (DeepSeek's first_k_dense_replace) stack
+        # apart from the rest, which hold the experts of an MoE model
+        if cfg.n_dense_layers:
+            params["dense_layers"] = _stack_init(
+                ks[6], cfg.n_dense_layers,
+                lambda k: _init_dense_layer(k, cfg, moe=False))
         params["layers"] = _stack_init(
-            ks[2], cfg.n_layers, lambda k: _init_dense_layer(k, cfg))
+            ks[2], cfg.n_layers - cfg.n_dense_layers,
+            lambda k: _init_dense_layer(k, cfg, moe=cfg.is_moe))
     pdt = jnp.dtype(cfg.param_dtype)
     if pdt != jnp.float32:
         # production dtype: bf16 weights on device; the fp32 master copy
@@ -195,25 +202,25 @@ def init_params(key, cfg: ModelConfig):
 # blocks (single-layer forward, used under scan)
 # ---------------------------------------------------------------------------
 
+def _ffn(p, h, cfg: ModelConfig):
+    """The layer's MLP or expert layer: (out, aux_loss, routes or None)."""
+    if "moe" in p:
+        return moe_forward(p["moe"], h, cfg)
+    return mlp_forward(p["mlp"], h), 0.0, None
+
+
 def _dense_block(p, x, cfg: ModelConfig, positions, *, mode="causal",
                  window=0, q_chunk=1024):
     if cfg.block_type == "parallel":                  # Cohere command-r
         h = apply_norm(cfg, p["ln1"], x)
         a = attention_forward(p["attn"], h, cfg, positions=positions,
                               mode=mode, window=window, q_chunk=q_chunk)
-        if cfg.is_moe:
-            m, aux = moe_forward(p["moe"], h, cfg)
-        else:
-            m, aux = mlp_forward(p["mlp"], h), 0.0
+        m, aux, _ = _ffn(p, h, cfg)
         return x + a + m, aux
     h = apply_norm(cfg, p["ln1"], x)
     x = x + attention_forward(p["attn"], h, cfg, positions=positions,
                               mode=mode, window=window, q_chunk=q_chunk)
-    h = apply_norm(cfg, p["ln2"], x)
-    if cfg.is_moe:
-        m, aux = moe_forward(p["moe"], h, cfg)
-    else:
-        m, aux = mlp_forward(p["mlp"], h), 0.0
+    m, aux, _ = _ffn(p, apply_norm(cfg, p["ln2"], x), cfg)
     return x + m, aux
 
 
@@ -320,8 +327,20 @@ def backbone(params, cfg: ModelConfig, x, positions, *, enc_out=None,
     else:
         def body(p, h):
             return _dense_block(p, h, cfg, positions, q_chunk=q_chunk)
-        x, aux = _scan_layers(params["layers"], x, body, cfg)
+        for name, _, _ in layer_stacks(cfg):
+            x, a = _scan_layers(params[name], x, body, cfg)
+            aux = aux + a
     return apply_norm(cfg, params["final_norm"], x), aux
+
+
+def layer_stacks(cfg: ModelConfig) -> list[tuple[str, str, int]]:
+    """The decoder's layer stacks in order: (params key, prefix of its
+    cache leaves, depth).  The leading dense layers, where the model has
+    them, keep their cache in leaves of their own (``dense_k``, ...), so
+    that neither stack's scan slices or concatenates the other's cache."""
+    dense = ([("dense_layers", "dense_", cfg.n_dense_layers)]
+             if cfg.n_dense_layers else [])
+    return dense + [("layers", "", cfg.n_layers - cfg.n_dense_layers)]
 
 
 def output_weights(params, cfg: ModelConfig, dtype):
@@ -365,6 +384,15 @@ def _kv_shape(cfg, b, s):
     return (b, s, cfg.n_kv_heads, cfg.head_dim)
 
 
+def _attn_cache_shapes(cfg, b, s) -> dict:
+    """One layer's self-attention cache leaves: keys and values, or MLA's
+    normalised latent and roped key."""
+    if cfg.attention == "mla":
+        return {"c_kv": (b, s, cfg.kv_lora_rank),
+                "k_pe": (b, s, cfg.qk_rope_head_dim)}
+    return {"k": _kv_shape(cfg, b, s), "v": _kv_shape(cfg, b, s)}
+
+
 def init_cache(cfg: ModelConfig, batch: int, cache_len: int, dtype=None):
     """Zero cache covering positions [0, cache_len), in the compute dtype
     unless ``dtype`` says otherwise."""
@@ -395,24 +423,31 @@ def init_cache(cfg: ModelConfig, batch: int, cache_len: int, dtype=None):
                 (n_tail, batch, cfg.conv_width - 1, cfg.lru_width), dtype)
         return cache
     L = cfg.n_layers
-    cache = {
-        "k": jnp.zeros((L, *_kv_shape(cfg, batch, cache_len)), dtype),
-        "v": jnp.zeros((L, *_kv_shape(cfg, batch, cache_len)), dtype),
-    }
     if cfg.is_encdec:
+        cache = {name: jnp.zeros((L, *shape), dtype) for name, shape in
+                 _attn_cache_shapes(cfg, batch, cache_len).items()}
         cache["cross_k"] = jnp.zeros((L, *_kv_shape(cfg, batch, cfg.n_frames)),
                                      dtype)
         cache["cross_v"] = jnp.zeros((L, *_kv_shape(cfg, batch, cfg.n_frames)),
                                      dtype)
-    return cache
+        return cache
+    return {prefix + name: jnp.zeros((depth, *shape), dtype)
+            for _, prefix, depth in layer_stacks(cfg)
+            for name, shape in _attn_cache_shapes(cfg, batch,
+                                                  cache_len).items()}
 
 
-def decode_step(params, cfg: ModelConfig, cache, tokens, pos):
+def decode_step(params, cfg: ModelConfig, cache, tokens, pos, *,
+                return_routes: bool = False):
     """tokens: (B, 1) int32; pos: absolute position, scalar int32 or a
     per-row (B,) int32 vector (continuous batching: each batch slot decodes
-    at its own position).  Returns (logits (B, V) fp32, new_cache)."""
+    at its own position).  Returns (logits (B, V) fp32, new_cache); with
+    ``return_routes`` (dense and MoE decoders) also the expert layers'
+    token -> held-expert assignments, (L_moe, B, n_experts) bool, or None
+    for a model without experts."""
     dtype = _compute_dtype(cfg)
     x = _embed(params, cfg, tokens, dtype)
+    routes = None
     if cfg.is_encdec:
         if jnp.ndim(pos) > 0:
             x = x + jnp.take(params["dec_pos"].astype(dtype),
@@ -502,37 +537,34 @@ def decode_step(params, cfg: ModelConfig, cache, tokens, pos):
         h = apply_norm(cfg, params["final_norm"], x)
     else:
         def step(x, inp):
-            p, k, v = inp
+            p, c = inp
+            hh = apply_norm(cfg, p["ln1"], x)
+            a, c_new = attention_decode(p["attn"], hh, c, cfg, pos=pos)
             if cfg.block_type == "parallel":
-                hh = apply_norm(cfg, p["ln1"], x)
-                a, kv_new = attention_decode(p["attn"], hh, {"k": k, "v": v},
-                                             cfg, pos=pos)
-                if cfg.is_moe:
-                    m, _ = moe_forward(p["moe"], hh, cfg)
-                else:
-                    m = mlp_forward(p["mlp"], hh)
+                m, _, routes = _ffn(p, hh, cfg)
                 x = x + a + m
             else:
-                hh = apply_norm(cfg, p["ln1"], x)
-                a, kv_new = attention_decode(p["attn"], hh, {"k": k, "v": v},
-                                             cfg, pos=pos)
                 x = x + a
-                hh = apply_norm(cfg, p["ln2"], x)
-                if cfg.is_moe:
-                    m, _ = moe_forward(p["moe"], hh, cfg)
-                else:
-                    m = mlp_forward(p["mlp"], hh)
+                m, _, routes = _ffn(p, apply_norm(cfg, p["ln2"], x), cfg)
                 x = x + m
-            return x, (kv_new["k"], kv_new["v"])
+            return x, (c_new, routes)
 
-        x, (k_new, v_new) = jax.lax.scan(
-            step, x, (params["layers"], cache["k"], cache["v"]))
-        new_cache = dict(cache, k=k_new, v=v_new)
+        new_cache = dict(cache)
+        for name, prefix, _ in layer_stacks(cfg):
+            c = {n: cache[prefix + n] for n in _attn_cache_shapes(cfg, 1, 1)}
+            x, (c, routes) = jax.lax.scan(step, x, (params[name], c))
+            new_cache.update({prefix + n: v for n, v in c.items()})
+        # the last stack's (L_moe, B, n_experts): token -> held-expert
+        # assignments computed
+        if routes is not None:
+            routes = routes.reshape(*routes.shape[:2], -1)
         h = apply_norm(cfg, params["final_norm"], x)
 
     w_out = output_weights(params, cfg, dtype)
     logits = (h[:, 0] @ w_out).astype(jnp.float32)
     logits = constrain(logits, ("batch", "vocab"))
+    if return_routes:
+        return logits, new_cache, routes
     return logits, new_cache
 
 
@@ -631,26 +663,18 @@ def prefill(params, cfg: ModelConfig, batch, cache_len: int, *,
                                       positions=positions, mode="causal",
                                       q_chunk=q_chunk, return_kv=True)
             if cfg.block_type == "parallel":
-                if cfg.is_moe:
-                    m, _ = moe_forward(p["moe"], hh, cfg)
-                else:
-                    m = mlp_forward(p["mlp"], hh)
+                m, _, _ = _ffn(p, hh, cfg)
                 x = x + a + m
             else:
                 x = x + a
-                hh2 = apply_norm(cfg, p["ln2"], x)
-                if cfg.is_moe:
-                    m, _ = moe_forward(p["moe"], hh2, cfg)
-                else:
-                    m = mlp_forward(p["mlp"], hh2)
+                m, _, _ = _ffn(p, apply_norm(cfg, p["ln2"], x), cfg)
                 x = x + m
-            k_c = jnp.zeros(_kv_shape(cfg, b, cache_len), dtype)
-            k_c = jax.lax.dynamic_update_slice(k_c, kv[0].astype(dtype),
-                                               (0, 0, 0, 0))
-            v_c = jnp.zeros(_kv_shape(cfg, b, cache_len), dtype)
-            v_c = jax.lax.dynamic_update_slice(v_c, kv[1].astype(dtype),
-                                               (0, 0, 0, 0))
-            return x, (k_c, v_c)
+            rows = []
+            for t in kv:          # each cache leaf's rows, padded
+                c = jnp.zeros((b, cache_len, *t.shape[2:]), dtype)
+                rows.append(jax.lax.dynamic_update_slice(
+                    c, t.astype(dtype), (0,) * t.ndim))
+            return x, tuple(rows)
 
         def encdec_step(x, p):
             hh = apply_norm(cfg, p["ln1"], x)
@@ -679,8 +703,10 @@ def prefill(params, cfg: ModelConfig, batch, cache_len: int, *,
             cache.update(k=ks, v=vs, cross_k=xks, cross_v=xvs)
         else:
             fn = jax.checkpoint(dense_step) if cfg.remat else dense_step
-            x, (ks, vs) = jax.lax.scan(fn, x, params["layers"])
-            cache.update(k=ks, v=vs)
+            for name, prefix, _ in layer_stacks(cfg):
+                x, rows = jax.lax.scan(fn, x, params[name])
+                cache.update({prefix + n: r for n, r in
+                              zip(_attn_cache_shapes(cfg, 1, 1), rows)})
         h = apply_norm(cfg, params["final_norm"], x)
 
     w_out = output_weights(params, cfg, dtype)

@@ -59,6 +59,9 @@ phases — ``serve.tick.faults`` (chaos and worker failures),
 ``.digest``).  A span that waits for the device closes only after the host
 holds the result, inside a ``*.wait`` child (``serve.decode.wait``,
 ``serve.prefill.wait``), so host work and device waits can be told apart.
+For an MoE model the ``serve.decode`` span also carries the tick's expert
+counts (``moe_experts_active``, ``moe_tokens``; see ``ServeMetrics``),
+fetched in the same transfer as the tick's tokens.
 With a tracer whose spans are also profiler annotations the phases line up
 with the device's operations.  ``serve.start`` reports the queue wait of
 each copy that takes a slot.
@@ -570,7 +573,7 @@ class ServeEngine:
             return
         tr = self.tracer
         with tr.span("serve.decode", step=t, live=len(busy),
-                     stalled=len(stalled)):
+                     stalled=len(stalled)) as span:
             toks = np.zeros((len(self.slots), 1), np.int32)
             poss = np.zeros((len(self.slots),), np.int32)
             live = np.zeros((len(self.slots),), bool)
@@ -581,10 +584,20 @@ class ServeEngine:
             nxt, logits, self.cache = self._serve(
                 self.params, self.cache, jnp.asarray(toks),
                 jnp.asarray(poss), jnp.asarray(live))
+            # an MoE step carries its expert counts beside the logits
+            logits, moe = logits if self.cfg.is_moe else (logits, None)
             with tr.span("serve.decode.wait"):
-                nxt = np.asarray(nxt)
+                if moe is None:
+                    nxt = np.asarray(nxt)
+                else:     # one transfer for the tokens and the counts
+                    nxt, moe = jax.device_get((nxt, moe))
                 rows = (np.asarray(logits) if self.ecfg.record_logits
                         else None)
+            if moe is not None:
+                self.metrics.moe_experts_active += int(moe[0])
+                self.metrics.moe_tokens += int(moe[1])
+                span.set(moe_experts_active=int(moe[0]),
+                         moe_tokens=int(moe[1]))
             for s in busy:
                 tok = int(nxt[s.sid, 0])
                 if rows is not None:

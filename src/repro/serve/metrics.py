@@ -21,6 +21,10 @@ Since the ``repro.obs`` unification the counters live in a
 ``serve_tokens_total{kind=...}``, ``serve_events_total{kind=...}``,
 ``serve_drops_total{reason=...}`` and ``serve_bytes_total{kind=...}``
 (bytes copied from the device to the host, such as decode snapshots) —
+plus, for an MoE model, two unlabeled counts of each batched decode tick
+summed over its expert layers: ``serve_moe_experts_active_total`` (held
+experts that computed at least one live token) and
+``serve_moe_tokens_total`` (live token -> held-expert assignments) —
 and :class:`ServeMetrics` is a thin compatibility shim: the legacy
 attribute names (``metrics.failures += 1``, ``metrics.rejected_on_arrival``)
 read and write the corresponding labeled series via
@@ -97,6 +101,8 @@ class ServeMetrics:
         "past_first_token_drops": ("serve_drops_total",
                                    {"reason": "past_first_token"}),
         "snapshot_bytes": ("serve_bytes_total", {"kind": "snapshot"}),
+        "moe_experts_active": ("serve_moe_experts_active_total", {}),
+        "moe_tokens": ("serve_moe_tokens_total", {}),
     }
 
     def __init__(self, registry: MetricsRegistry | None = None) -> None:
@@ -117,6 +123,14 @@ class ServeMetrics:
                 "serve_bytes_total",
                 "bytes copied from the device to the host, by kind",
                 ("kind",)),
+            "serve_moe_experts_active_total": self.registry.counter(
+                "serve_moe_experts_active_total",
+                "held experts that computed at least one live token, "
+                "summed over expert layers and decode ticks", ()),
+            "serve_moe_tokens_total": self.registry.counter(
+                "serve_moe_tokens_total",
+                "live token -> held-expert assignments, summed over "
+                "expert layers and decode ticks", ()),
         }
 
     def __getattr__(self, name):

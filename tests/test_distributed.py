@@ -92,6 +92,15 @@ def test_cache_specs_fall_back_when_indivisible():
     assert tuple(specs["x_tm"])[2] == "model"
 
 
+def test_cache_specs_shard_a_leading_dense_stack_as_the_rest():
+    cfg = get_config("deepseek_v2_lite")
+    cache = jax.eval_shape(lambda: lm.init_cache(cfg, 32, 1024))
+    specs = pshard.cache_specs(cache, cfg, _ProdMeshStub())
+    for leaf in ("c_kv", "k_pe"):
+        assert tuple(specs[leaf]) == (None, "data", "model", None)
+        assert tuple(specs["dense_" + leaf]) == tuple(specs[leaf])
+
+
 def test_param_specs_fall_back_for_indivisible_vocab():
     # granite-moe vocab 49155 does not divide model=16 -> replicated
     cfg, ab = _abstract("granite_moe_1b")

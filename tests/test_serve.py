@@ -126,8 +126,10 @@ def test_slot_get_set_roundtrip(arch):
         assert (got[0] == 0).all() and (got[2] == 0).all()  # untouched
 
 
-# the snapshot tests' families: dense KV, RWKV state, RG-LRU hybrid, enc-dec
-SNAPSHOT_ARCHS = ("olmo-1b", "rwkv6-3b", "recurrentgemma-2b", "whisper-small")
+# the snapshot tests' families: dense KV, RWKV state, RG-LRU hybrid, enc-dec,
+# MLA's latent cache beside a leading dense layer's
+SNAPSHOT_ARCHS = ("olmo-1b", "rwkv6-3b", "recurrentgemma-2b", "whisper-small",
+                  "deepseek-v2-lite")
 
 
 def _leaf_names(cfg, cache_len):
@@ -144,6 +146,7 @@ def _leaf_names(cfg, cache_len):
     # ...below it, it is indexed by position and append-only
     ("recurrentgemma-2b", 12, {"k", "v"}),
     ("whisper-small", 48, {"k", "v"}),
+    ("deepseek-v2-lite", 48, {"c_kv", "k_pe", "dense_c_kv", "dense_k_pe"}),
 ])
 def test_snapshot_leaf_classification(arch, cache_len, append_only):
     """Leaves that grow with the cache length are append-only along that
@@ -336,7 +339,7 @@ def test_engine_state_bounded_over_many_requests(tiny_setup):
 
 
 ALL_ARCHS = ("rwkv6-3b", "recurrentgemma-2b", "whisper-small",
-             "llava-next-mistral-7b")
+             "llava-next-mistral-7b", "deepseek-v2-lite")
 
 
 @pytest.mark.parametrize("arch", ALL_ARCHS)

@@ -10,6 +10,7 @@ The topology is described inside a fixture, never at import: only one
 process at a time may load the TPU library, and every test worker imports
 this file.
 """
+import dataclasses
 import math
 import os
 import re
@@ -106,6 +107,49 @@ def test_olmo_prefill_fits_one_chip(olmo, one_chip, no_compile_cache):
     last = jax.ShapeDtypeStruct((1,), jnp.int32, sharding=one_chip)
     compiled = prefill.lower(params, {"tokens": tokens}, last).compile()
     _fits(compiled)
+
+
+@pytest.fixture(scope="module")
+def v2_lite(one_chip):
+    """DeepSeek-V2-Lite as the benchmark runs it: 12 layers, 8 of 64
+    routed experts held (``bench/configs/deepseek-v2-lite.json``)."""
+    cfg = dataclasses.replace(get_config("deepseek-v2-lite"), n_layers=12,
+                              n_experts=8)
+    params = jax.eval_shape(lambda: lm.init_params(jax.random.key(0), cfg))
+    return cfg, _on(one_chip, params)
+
+
+def test_v2_lite_masked_serve_step_fits_one_chip(v2_lite, one_chip,
+                                                 no_compile_cache):
+    """32 slots of 9216 latent rows beside the weights: the deepest stage
+    the compiler accepts (13 layers are refused)."""
+    cfg, params = v2_lite
+    slots, cache_len = 32, 9216
+    cache = _on(one_chip, jax.eval_shape(
+        lambda: lm.init_cache(cfg, slots, cache_len)))
+    step = jax.jit(make_serve_step(cfg, cache_axes=cache_batch_axes(
+        cfg, cache_len)), donate_argnums=(1,))
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = step.lower(params, cache, sds((slots, 1), jnp.int32),
+                          sds((slots,), jnp.int32),
+                          sds((slots,), jnp.bool_)).compile()
+    _fits(compiled)
+
+
+def test_v2_lite_longest_prefill_fits_beside_the_cache(v2_lite, one_chip,
+                                                       no_compile_cache):
+    cfg, params = v2_lite
+    prefill = jax.jit(make_prefill_step(cfg, 9216, q_chunk=64,
+                                        with_last_idx=True))
+    tokens = jax.ShapeDtypeStruct((1, 8192), jnp.int32, sharding=one_chip)
+    last = jax.ShapeDtypeStruct((1,), jnp.int32, sharding=one_chip)
+    compiled = prefill.lower(params, {"tokens": tokens}, last).compile()
+    cache = jax.eval_shape(lambda: lm.init_cache(cfg, 32, 9216))
+    resident = sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(cache))
+    assert _fits(compiled) + resident < V5E_HBM_BYTES
 
 
 def test_pairwise_distance_kernel_compiles_for_the_chip(one_chip,
