@@ -234,7 +234,9 @@ def trace_path(d: str, layer: str, kind: str) -> str:
 
 def run_matrix(args) -> list[dict]:
     cfg = get_config(args.arch, tiny=True)
-    serve_params = lm.init_params(jax.random.key(args.seed), cfg)
+    # the serving weights once, for every serve cell's engine
+    serve_params = lm.serving_params(
+        lm.init_params(jax.random.key(args.seed), cfg), cfg)
     ctx = obs.setup(getattr(args, "trace_dir", "") or None,
                     dump_on_fault=True)
     tracer = ctx.tracer if ctx.enabled else None

@@ -121,7 +121,10 @@ def run(fast: bool = True, *, envs=None, seed: int = 0,
         envs = (("normal",) if quick
                 else ("normal", "unstable") if fast else H.ENVS)
     cfg = get_config(arch, tiny=fast)
-    params = lm.init_params(jax.random.key(seed), cfg)
+    # the serving weights once, for every cell's engine: an engine casts
+    # (and releases) nothing of a tree already in the compute dtype
+    params = lm.serving_params(lm.init_params(jax.random.key(seed), cfg),
+                               cfg)
     if quick:
         workload_kw = dict(n_short=10, n_medium=4, n_long=2,
                            arrival_spread=60, slack_factor=4.0)

@@ -60,7 +60,8 @@ def main() -> None:
     args = ap.parse_args()
 
     cfg = get_config(args.arch, tiny=True)
-    params = lm.init_params(jax.random.key(0), cfg)
+    # the serving weights once, for both runs' engines
+    params = lm.serving_params(lm.init_params(jax.random.key(0), cfg), cfg)
     reqs = make_requests(cfg, args.requests)
 
     print("clean run (no failures):")

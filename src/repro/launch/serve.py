@@ -142,7 +142,7 @@ class ServeRun:
     """What one continuous-engine run leaves behind for its caller."""
     engine: ServeEngine
     requests: list[Request]
-    params: dict
+    params: dict              # the float32 weights the engine was built from
     cache_len: int
     wall_s: float
     summary: dict
@@ -190,6 +190,10 @@ def continuous_main(cfg, mesh, args, *, record_logits: bool = False
         t0 = time.time()
         metrics = engine.run(max_steps=args.max_steps)
         wall = time.time() - t0
+        if any(leaf.is_deleted() for leaf in jax.tree.leaves(params)):
+            # the engine took the weights and released those it cast:
+            # the float32 ones the reference reads are made again
+            params = _sharded_params(cfg, mesh, args.seed)
     s = metrics.summary(engine.step_no)
     tok_s = metrics.decode_tokens / max(wall, 1e-9)
     print(f"arch={cfg.name} ({cfg.param_count() / 1e6:.0f}M params) "

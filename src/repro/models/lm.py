@@ -3,6 +3,7 @@
 Pure-functional API:
 
   init_params(key, cfg)                          -> params pytree
+  serving_params(params, cfg)                    -> compute-dtype weights
   forward_train(params, cfg, batch)              -> (loss, metrics)
   prefill(params, cfg, batch, cache_len)         -> (last_logits, cache)
   init_cache(cfg, batch_size, cache_len)         -> cache pytree
@@ -196,6 +197,31 @@ def init_params(key, cfg: ModelConfig):
         # lives (sharded) in the optimizer state (ZeRO-1)
         params = jax.tree.map(lambda x: x.astype(pdt), params)
     return params
+
+
+# Leaves the model reads in float32: the norms' gains and biases (MLA's
+# ``kv_norm`` too), RWKV-6's decay base ``ww``, bonus ``u`` and head
+# group-norm gain ``ln_scale``, and RG-LRU's gate biases ``ba``/``bx`` and
+# ``lam``.  It reads every other leaf only as ``.astype(compute_dtype)``.
+FLOAT32_LEAVES = frozenset({"scale", "bias", "ww", "u", "ln_scale", "ba",
+                            "bx", "lam"})
+
+
+def serving_params(params, cfg: ModelConfig):
+    """The weights as the model reads them: each leaf read only as
+    ``.astype(compute_dtype)`` cast to the compute dtype, the leaves of
+    ``FLOAT32_LEAVES`` left as they are.  Every output is the same bit for
+    bit, since each matmul already ran on these values; the programs just
+    stop casting the weights on every call.  A leaf already in the compute
+    dtype is returned as it is."""
+    dtype = _compute_dtype(cfg)
+
+    def cast(path, x):
+        if path[-1].key in FLOAT32_LEAVES or x.dtype == dtype:
+            return x
+        return x.astype(dtype)
+
+    return jax.tree_util.tree_map_with_path(cast, params)
 
 
 # ---------------------------------------------------------------------------

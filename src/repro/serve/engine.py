@@ -62,6 +62,7 @@ holds the result, inside a ``*.wait`` child (``serve.decode.wait``,
 For an MoE model the ``serve.decode`` span also carries the tick's expert
 counts (``moe_experts_active``, ``moe_tokens``; see ``ServeMetrics``),
 fetched in the same transfer as the tick's tokens.
+Building the engine casts its weights inside a ``serve.weights`` span.
 With a tracer whose spans are also profiler annotations the phases line up
 with the device's operations.  ``serve.start`` reports the queue wait of
 each copy that takes a slot.
@@ -70,6 +71,7 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -91,7 +93,7 @@ from .snapshot import (DecodeSnapshot, Lineage, SlotLayout, SnapshotStore,
                        slot_get, slot_set)
 
 __all__ = ["EngineConfig", "ServeEngine", "engine_supported",
-           "prefill_inputs", "prefill_len"]
+           "prefill_inputs", "prefill_len", "take_serving_weights"]
 
 
 def engine_supported(cfg: ModelConfig) -> tuple[bool, str]:
@@ -128,6 +130,28 @@ def prefill_inputs(cfg: ModelConfig, req: Request, seq: int) -> dict:
         batch["image_embeds"] = jnp.asarray(
             np.asarray(req.image_embeds, np.float32))[None]
     return batch
+
+
+# one elementwise program a leaf shape: the copy keeps the leaf's sharding
+_cast = jax.jit(lambda x, dtype: x.astype(dtype), static_argnums=1)
+
+
+def take_serving_weights(params, cfg: ModelConfig):
+    """``lm.serving_params`` of ``params``, cast leaf by leaf on each
+    leaf's own devices and sharding.  Each given leaf that is cast is
+    released once its copy exists, so the peak is the given tree plus one
+    leaf; the leaves left as they are are the given ones."""
+    want = jax.eval_shape(functools.partial(lm.serving_params, cfg=cfg),
+                          params)
+
+    def take(leaf, to):
+        if leaf.dtype == to.dtype:
+            return leaf
+        out = _cast(leaf, to.dtype).block_until_ready()
+        leaf.delete()
+        return out
+
+    return jax.tree.map(take, params, want)
 
 
 def init_slot_cache(cfg: ModelConfig, slots: int, cache_len: int):
@@ -186,6 +210,14 @@ class _Slot:
 
 
 class ServeEngine:
+    """The continuous-batching engine over ``pool``'s slots.
+
+    The engine takes the weights it is given: at build it casts each leaf
+    the model reads only in the compute dtype (``lm.serving_params``) and
+    releases the given leaf, so a caller that needs the float32 weights
+    afterwards makes them again or passes a copy.  ``self.params`` is the
+    tree every program of the engine is called with."""
+
     def __init__(self, cfg: ModelConfig, ecfg: EngineConfig | None = None, *,
                  pool: WorkerPool, policy: ReplicaPolicy | None = None,
                  params=None, metrics: ServeMetrics | None = None,
@@ -209,9 +241,11 @@ class ServeEngine:
         self.tracer = tracer if tracer is not None else NULL_TRACER
         self.shed: set[int] = set()   # rids dropped in degraded mode
         self.policy = policy or uniform_policy(1)
-        self.params = (params if params is not None
-                       else lm.init_params(jax.random.key(seed), cfg))
         self.metrics = metrics or ServeMetrics()
+        # before the cache: the peak is the given weights plus one leaf
+        self.params = self._take_weights(
+            params if params is not None
+            else lm.init_params(jax.random.key(seed), cfg))
         self.queue = AdmissionQueue(max_depth=self.ecfg.max_queue_depth,
                                     drain_rate=max(pool.n_slots, 1))
         self.rejected: dict[int, int] = {}   # rid -> retry_after hint
@@ -256,6 +290,21 @@ class ServeEngine:
         self._lineage = [Lineage(self.layout.chunk) for _ in self.slots]
         self._prefill_fns: dict[int, callable] = {}
         self.logit_log: list[tuple[int, int, np.ndarray]] = []
+
+    def _take_weights(self, params):
+        """``take_serving_weights`` inside a ``serve.weights`` span; sets
+        ``serve_weight_bytes{dtype=...}``."""
+        with self.tracer.span("serve.weights"):
+            params = take_serving_weights(params, self.cfg)
+            nbytes = collections.Counter()
+            for leaf in jax.tree.leaves(params):
+                nbytes[leaf.dtype.name] += leaf.nbytes
+            gauge = self.metrics.registry.gauge(
+                "serve_weight_bytes", "bytes of the serving weights, by "
+                "dtype", ("dtype",))
+            for name, n in nbytes.items():
+                gauge.set(n, dtype=name)
+        return params
 
     def _compile_snapshot_programs(self) -> None:
         """Compile the snapshot's chunk and state programs now, against the

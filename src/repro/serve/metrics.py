@@ -31,6 +31,8 @@ read and write the corresponding labeled series via
 ``__getattr__``/``__setattr__``, so the engine and every existing test keep
 working unchanged while exporters see one registry.
 Pass a shared registry to pool serving series with the rest of a run.
+``ServeEngine`` also sets one gauge at build, ``serve_weight_bytes{dtype=...}``:
+the bytes of its serving weights by dtype.
 """
 from __future__ import annotations
 

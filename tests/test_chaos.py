@@ -22,6 +22,7 @@ import collections
 import os
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -69,6 +70,13 @@ def _req(rid, plen, newt, *, arrival=0, deadline=None, vocab=256, seed=0):
                    max_new_tokens=newt, arrival=arrival, deadline=deadline)
 
 
+def _copy(params):
+    """A copy of the weights for one engine: an engine takes the weights
+    it is given and releases those it casts, and the fixture builds
+    several."""
+    return jax.tree.map(jnp.copy, params)
+
+
 def _engine(cfg, params, reqs, *, workers=2, slots=2, chaos=None,
             policy=None, snapshot_lambda=4, max_steps=2_000, tracer=None,
             before_run=None):
@@ -78,7 +86,7 @@ def _engine(cfg, params, reqs, *, workers=2, slots=2, chaos=None,
     engine = ServeEngine(
         cfg, EngineConfig(cache_len=cache_len, q_chunk=32,
                           snapshot_lambda=snapshot_lambda),
-        pool=pool, policy=policy or uniform_policy(1), params=params,
+        pool=pool, policy=policy or uniform_policy(1), params=_copy(params),
         chaos=chaos, tracer=tracer)
     if before_run is not None:
         before_run(engine)
@@ -519,7 +527,7 @@ def test_serve_bounded_admission_under_capacity_loss(serve_setup):
     engine = ServeEngine(
         cfg, EngineConfig(cache_len=cache_len, q_chunk=32,
                           snapshot_lambda=4, max_queue_depth=4),
-        pool=pool, policy=uniform_policy(2), params=params,
+        pool=pool, policy=uniform_policy(2), params=_copy(params),
         chaos=ChaosEngine(trace))
     admitted = []
     for r in reqs:

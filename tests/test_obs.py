@@ -350,7 +350,8 @@ def test_chaos_matrix_serve_cell_row_identical_traced(tmp_path):
         "benchmarks.chaos_matrix",
         reason="benchmarks/ not importable from this rootdir")
     cfg = get_config("olmo-1b", tiny=True)
-    params = lm.init_params(jax.random.key(1), cfg)
+    # the serving weights, as run_matrix gives every serve cell's engine
+    params = lm.serving_params(lm.init_params(jax.random.key(1), cfg), cfg)
     trace = chaos_matrix.cell_trace("unstable", "serve", HOST_CRASH,
                                     horizon=120, n_targets=4, seed=5)
     kw = dict(n_requests=4, max_steps=400, seed=5)

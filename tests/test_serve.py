@@ -8,6 +8,7 @@ import textwrap
 from pathlib import Path
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -201,6 +202,13 @@ def _cache_len_for(cfg, reqs):
     return cache_len
 
 
+def _copy(params):
+    """A copy of the weights for one engine: an engine takes the weights
+    it is given and releases those it casts, and the module's fixture
+    builds several."""
+    return jax.tree.map(jnp.copy, params)
+
+
 def _engine(cfg, params, reqs, *, fail=None, snapshot_lambda=4,
             policy=None, retain_completed=4096, tracer=None):
     cache_len = _cache_len_for(cfg, reqs)
@@ -211,7 +219,7 @@ def _engine(cfg, params, reqs, *, fail=None, snapshot_lambda=4,
         cfg, EngineConfig(cache_len=cache_len, q_chunk=32,
                           snapshot_lambda=snapshot_lambda,
                           retain_completed=retain_completed),
-        pool=pool, policy=policy or uniform_policy(1), params=params,
+        pool=pool, policy=policy or uniform_policy(1), params=_copy(params),
         tracer=tracer)
 
 
@@ -264,7 +272,8 @@ def test_engine_rejects_oversized_request(tiny_setup):
     cache_len = 16
     pool = WorkerPool(1, 2, mtbf_steps=0.0, seed=0)
     engine = ServeEngine(cfg, EngineConfig(cache_len=cache_len, q_chunk=32),
-                         pool=pool, policy=uniform_policy(1), params=params)
+                         pool=pool, policy=uniform_policy(1),
+                         params=_copy(params))
     engine.submit(engine_req)
     with pytest.raises(ValueError):
         engine.submit(_req(1, 20, 16, vocab=cfg.vocab_size))
@@ -288,7 +297,8 @@ def test_engine_idle_slot_cache_row_untouched(tiny_setup):
     cache_len = _cache_len_for(cfg, reqs)
     pool = WorkerPool(2, 2, mtbf_steps=0.0, seed=0)
     engine = ServeEngine(cfg, EngineConfig(cache_len=cache_len, q_chunk=32),
-                         pool=pool, policy=uniform_policy(1), params=params)
+                         pool=pool, policy=uniform_policy(1),
+                         params=_copy(params))
     for r in reqs:
         engine.submit(r)
     while 0 not in engine.completed:
@@ -405,7 +415,7 @@ def test_delta_snapshot_resume_on_seq_sharded_cache():
     failure-free tokens.  In a child process of its own, since the device
     count is fixed when JAX starts."""
     code = textwrap.dedent("""
-        import jax, numpy as np
+        import jax, jax.numpy as jnp, numpy as np
         from repro.configs import get_config
         from repro.distributed.sharding import use_rules
         from repro.launch.mesh import make_mesh
@@ -428,7 +438,8 @@ def test_delta_snapshot_resume_on_seq_sharded_cache():
             with use_rules(make_mesh(4)):
                 e = ServeEngine(cfg, EngineConfig(
                     cache_len=48, q_chunk=32, snapshot_lambda=2),
-                    pool=pool, policy=uniform_policy(1), params=params)
+                    pool=pool, policy=uniform_policy(1),
+                    params=jax.tree.map(jnp.copy, params))
                 for r in reqs:
                     e.submit(r)
                 e.run(max_steps=2000)

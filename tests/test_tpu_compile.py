@@ -80,10 +80,8 @@ def _fits(compiled) -> int:
     return used
 
 
-def test_olmo_masked_serve_step_fits_one_chip(olmo, one_chip,
-                                              no_compile_cache):
-    cfg, params = olmo
-    slots, cache_len = 8, 2048
+def _serve_step(cfg, params, one_chip, slots, cache_len):
+    """The engine's masked serve step compiled for one described chip."""
     cache = _on(one_chip, jax.eval_shape(
         lambda: lm.init_cache(cfg, slots, cache_len)))
     step = jax.jit(make_serve_step(cfg, cache_axes=cache_batch_axes(
@@ -92,10 +90,15 @@ def test_olmo_masked_serve_step_fits_one_chip(olmo, one_chip,
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    compiled = step.lower(params, cache, sds((slots, 1), jnp.int32),
-                          sds((slots,), jnp.int32),
-                          sds((slots,), jnp.bool_)).compile()
-    _fits(compiled)
+    return step.lower(params, cache, sds((slots, 1), jnp.int32),
+                      sds((slots,), jnp.int32),
+                      sds((slots,), jnp.bool_)).compile()
+
+
+def test_olmo_masked_serve_step_fits_one_chip(olmo, one_chip,
+                                              no_compile_cache):
+    cfg, params = olmo
+    _fits(_serve_step(cfg, params, one_chip, 8, 2048))
 
 
 def test_olmo_prefill_fits_one_chip(olmo, one_chip, no_compile_cache):
@@ -121,22 +124,44 @@ def v2_lite(one_chip):
 
 def test_v2_lite_masked_serve_step_fits_one_chip(v2_lite, one_chip,
                                                  no_compile_cache):
-    """32 slots of 9216 latent rows beside the weights: the deepest stage
-    the compiler accepts (13 layers are refused)."""
+    """32 slots of 9216 latent rows beside the float32 weights: the
+    deepest stage the compiler accepts (13 layers are refused)."""
     cfg, params = v2_lite
-    slots, cache_len = 32, 9216
-    cache = _on(one_chip, jax.eval_shape(
-        lambda: lm.init_cache(cfg, slots, cache_len)))
-    step = jax.jit(make_serve_step(cfg, cache_axes=cache_batch_axes(
-        cfg, cache_len)), donate_argnums=(1,))
+    _fits(_serve_step(cfg, params, one_chip, 32, 9216))
 
-    def sds(shape, dtype):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
-    compiled = step.lower(params, cache, sds((slots, 1), jnp.int32),
-                          sds((slots,), jnp.int32),
-                          sds((slots,), jnp.bool_)).compile()
-    _fits(compiled)
+@pytest.fixture(scope="module")
+def coder(one_chip):
+    """deepseek-coder-33b as the benchmark runs it: 4 layers at published
+    width (``bench/configs/deepseek-coder-33b.json``)."""
+    cfg = dataclasses.replace(get_config("deepseek-coder-33b"), n_layers=4)
+    params = jax.eval_shape(lambda: lm.init_params(jax.random.key(0), cfg))
+    return cfg, _on(one_chip, params)
+
+
+def _bytes_accessed(compiled) -> float:
+    cost = compiled.cost_analysis()
+    return (cost[0] if isinstance(cost, list) else cost)["bytes accessed"]
+
+
+@pytest.mark.parametrize("model, slots, cache_len, gib, share", [
+    ("coder", 12, 4608, 8, 0.5),      # float32 weights: 15.53 GiB
+    ("v2_lite", 32, 9216, 12, 0.75),  # float32 weights: 15.88 GiB
+])
+def test_serving_weights_shrink_the_serve_step(request, one_chip,
+                                               no_compile_cache, model,
+                                               slots, cache_len, gib, share):
+    """Each cell's masked serve step on the engine's serving weights: no
+    copy of the weights in the compute dtype made on every call, so the
+    program fits in ``gib`` GiB and moves under ``share`` of the bytes it
+    moves on float32 weights."""
+    cfg, params = request.getfixturevalue(model)
+    served = _on(one_chip, jax.eval_shape(
+        lambda p: lm.serving_params(p, cfg), params))
+    f32 = _serve_step(cfg, params, one_chip, slots, cache_len)
+    bf16 = _serve_step(cfg, served, one_chip, slots, cache_len)
+    assert _fits(bf16) < gib * 2 ** 30
+    assert _bytes_accessed(bf16) < share * _bytes_accessed(f32)
 
 
 def test_v2_lite_longest_prefill_fits_beside_the_cache(v2_lite, one_chip,
